@@ -67,8 +67,7 @@ def run() -> None:
     proc = subprocess.run([sys.executable, "-c", SCRIPT], env=env,
                           capture_output=True, text=True, timeout=600)
     if proc.returncode != 0:
-        emit("alltoall/error", 0.0, proc.stderr[-200:].replace(",", ";"))
-        return
+        raise RuntimeError(proc.stderr[-2000:])
     import json
     out = json.loads(proc.stdout.strip().splitlines()[-1])
     for sched, st in out.items():

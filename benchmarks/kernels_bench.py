@@ -71,12 +71,14 @@ def _temp_bytes(fn, *args) -> int | None:
 
 
 def _kernel_vmem_bytes(block: int, out_block: int) -> int:
-    """Analytic per-grid-step VMEM working set of the fused kernel: six
-    candidate blocks (5 x 4-byte lanes + the 1-byte ok mask) and four
-    4-byte output tiles that persist across the candidate sweep —
-    everything the kernel ever materialises (no [size+1] scatter
-    tables, no full-length at_min / is_win masks)."""
-    return block * (5 * 4 + 1) + out_block * 4 * 4
+    """Analytic per-grid-step bytes of the fused kernel's blocks: five
+    4-byte candidate lanes (the ok mask is folded into the index lane)
+    and four 4-byte output tiles that persist across the candidate
+    sweep (no [size+1] scatter tables, no full-length at_min / is_win
+    masks).  Logical bytes: the TPU pads a (1, block) row to 8 sublanes
+    and an (out_block, 1) column to 128 lanes, and the per-step
+    [out_block, block] hit matrix is not counted."""
+    return block * 5 * 4 + out_block * 4 * 4
 
 
 def run_scatter_min(L: int, size: int, block: int, out_block: int,

@@ -68,8 +68,7 @@ def run() -> None:
     proc = subprocess.run([sys.executable, "-c", SCRIPT], env=env,
                           capture_output=True, text=True, timeout=900)
     if proc.returncode != 0:
-        emit("preprocessing/error", 0.0, proc.stderr[-200:].replace(",", ";"))
-        return
+        raise RuntimeError(proc.stderr[-2000:])
     out = json.loads(proc.stdout.strip().splitlines()[-1])
     for fam, rec in out.items():
         on, off = rec["True"]["us"], rec["False"]["us"]

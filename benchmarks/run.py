@@ -10,23 +10,15 @@ Prints ``name,us_per_call,derived`` CSV.  Figure mapping:
 """
 from __future__ import annotations
 
-import sys
-import traceback
-
 
 def main() -> None:
     print("name,us_per_call,derived")
     from benchmarks import (alltoall, kernels_bench, phases, preprocessing,
                             sharded_scaling, strong_scaling, weak_scaling)
+    # a module that crashes ends the run with a nonzero exit
     for mod in (weak_scaling, alltoall, preprocessing, strong_scaling,
                 sharded_scaling, phases, kernels_bench):
-        try:
-            mod.run()
-        except Exception as e:  # keep the harness going; report the row
-            print(f"{mod.__name__}/CRASH,0.0,"
-                  f"{type(e).__name__}:{str(e)[:120]}".replace(",", ";"),
-                  flush=True)
-            traceback.print_exc(file=sys.stderr)
+        mod.run()
 
 
 if __name__ == "__main__":

@@ -275,13 +275,7 @@ def _run_script(smoke: bool) -> dict:
 
 
 def run(smoke: bool = False) -> None:
-    try:
-        out = _run_script(smoke)
-    except Exception as e:
-        emit("serve_msf/error", 0.0, str(e)[-200:].replace(",", ";"))
-        if smoke:
-            raise
-        return
+    out = _run_script(smoke)
     t = out["traffic"]
     emit("serve_msf/traffic", t["wall_s"] * 1e6,
          f"req_per_s={t['requests_per_s']:.2f};"

@@ -271,13 +271,7 @@ def _run_script(smoke: bool) -> dict:
 
 
 def run(smoke: bool = False) -> None:
-    try:
-        out = _run_script(smoke)
-    except Exception as e:
-        emit("sharded_scaling/error", 0.0, str(e)[-200:].replace(",", ";"))
-        if smoke:
-            raise
-        return
+    out = _run_script(smoke)
     for n, rec in out["memory"].items():
         shrink = (rec["replicated"]["label_ints_per_device"]
                   / max(rec["sharded"]["label_ints_per_device"], 1))

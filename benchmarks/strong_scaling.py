@@ -49,9 +49,7 @@ def run() -> None:
     proc = subprocess.run([sys.executable, "-c", SCRIPT], env=env,
                           capture_output=True, text=True, timeout=900)
     if proc.returncode != 0:
-        emit("strong_scaling/error", 0.0,
-             proc.stderr[-200:].replace(",", ";"))
-        return
+        raise RuntimeError(proc.stderr[-2000:])
     out = json.loads(proc.stdout.strip().splitlines()[-1])
     base_cap = out["1"]["cap_per_shard"]
     for p, rec in out.items():

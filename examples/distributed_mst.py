@@ -19,6 +19,7 @@ import jax  # noqa: E402
 import numpy as np  # noqa: E402
 from jax.sharding import Mesh  # noqa: E402
 
+from repro.compile_cache import place_compile_cache  # noqa: E402
 from repro.core import oracle  # noqa: E402
 from repro.core.distributed import build_dist_graph, distributed_msf  # noqa: E402
 from repro.core.distributed_sharded import distributed_sharded_msf  # noqa: E402
@@ -33,6 +34,7 @@ def main() -> None:
     ap.add_argument("--degree", type=float, default=16.0)
     args = ap.parse_args()
 
+    place_compile_cache()
     p = jax.device_count()
     mesh = Mesh(np.array(jax.devices()), ("data",))
     print(f"devices: {p}  family: {args.family}")

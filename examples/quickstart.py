@@ -4,6 +4,7 @@
 """
 import numpy as np
 
+from repro.compile_cache import place_compile_cache
 from repro.core import oracle
 from repro.core.graph import from_numpy
 from repro.core.mst import minimum_spanning_forest
@@ -11,6 +12,7 @@ from repro.data import generators
 
 
 def main() -> None:
+    place_compile_cache()
     u, v, w, n = generators.generate("rgg2d", 2048, avg_degree=8.0, seed=0)
     print(f"graph: rgg2d n={n} m={len(u)}")
     edges = from_numpy(u, v, w, n)

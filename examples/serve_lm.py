@@ -6,12 +6,14 @@ import time
 
 import jax
 
+from repro.compile_cache import place_compile_cache
 from repro.configs.base import get_arch
 from repro.models.model import init_params
 from repro.serve.engine import Request, ServeEngine
 
 
 def main() -> None:
+    place_compile_cache()
     cfg = get_arch("qwen2-1.5b").smoke
     params = init_params(cfg, jax.random.key(0))
     eng = ServeEngine(cfg, params, batch_slots=4, max_len=96,

@@ -9,6 +9,7 @@ import dataclasses
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import place_compile_cache
 from repro.configs.base import get_arch
 from repro.train.optimizer import AdamWConfig
 from repro.train.train_loop import TrainConfig, train
@@ -29,6 +30,7 @@ def synthetic_data(cfg, batch=16, seq=64, seed=0):
 
 
 def main() -> None:
+    place_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3.2-3b")
     ap.add_argument("--steps", type=int, default=200)

@@ -1,108 +1,34 @@
-"""Version bridge for the shard_map / varying-manual-axes (vma) API split.
+"""The manual-collective surface every module imports.
 
-The repo targets two JAX generations at once (EXPERIMENTS.md §Compat):
+Values inside ``shard_map`` carry *varying manual axes* (vma) metadata,
+inspectable through ``jax.typeof(x).vma``.  With ``check_vma=True`` (the
+default) a replicated value must be cast to varying before it is mixed
+with varying operands; ``vary`` does that for exactly the axes it is
+missing, so call sites need not track which axes a value already varies
+over:
 
-* **JAX ≥ 0.6** — ``jax.shard_map`` is public, values inside shard_map
-  carry *varying manual axes* (vma) metadata inspectable via
-  ``jax.typeof(x).vma``, and ``lax.pvary`` promotes a replicated value to
-  a varying one (required before mixing it with varying operands when
-  ``check_vma=True``).
-* **JAX 0.4.x** — shard_map lives in ``jax.experimental.shard_map``,
-  there is no vma system (``lax.pvary`` / ``jax.typeof`` do not exist),
-  and the equivalent of disabling vma checking is ``check_rep=False``.
-
-Every module in this repo imports the manual-collective surface from
-here instead of from ``jax`` directly:
-
-    from repro.compat import shard_map, pvary, vma_of, vary, psum_scatter
-
-On 0.4.x ``pvary`` is the identity and ``vma_of`` returns an empty
-frozenset, so code written for the vma world runs unchanged (the checks
-it satisfies simply do not exist).  ``shard_map`` maps the ``check``
-knob onto ``check_vma`` (new) or ``check_rep`` (old); by default the
-old path disables replication checking, which is the semantic match for
-vma-annotated programs.
+    from repro.compat import shard_map, vma_of, vary, axis_size
 """
 from __future__ import annotations
 
-from typing import Optional, Sequence
+from typing import Sequence
 
 import jax
 from jax import lax
 
-__all__ = ["HAS_VMA", "HAS_NATIVE_SHARD_MAP", "shard_map", "pvary",
-           "vma_of", "vary", "psum_scatter", "axis_size"]
+__all__ = ["shard_map", "vma_of", "vary", "axis_size"]
+
+shard_map = jax.shard_map
+axis_size = lax.axis_size
 
 
-def _jax_has(name: str) -> bool:
-    # jax >= 0.4.30 raises AttributeError through a deprecation shim for
-    # names that only exist in newer versions, so hasattr() is accurate.
-    return hasattr(jax, name)
-
-
-HAS_NATIVE_SHARD_MAP = _jax_has("shard_map")
-HAS_VMA = hasattr(lax, "pvary") and _jax_has("typeof")
-
-if HAS_NATIVE_SHARD_MAP:
-    _shard_map_impl = jax.shard_map
-else:  # 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-
-def shard_map(f, *, mesh=None, in_specs, out_specs,
-              check: Optional[bool] = None, **kwargs):
-    """Uniform shard_map entry point.
-
-    ``check`` maps to ``check_vma`` (JAX ≥ 0.6) or ``check_rep``
-    (JAX 0.4.x).  Default: vma checking stays on where it exists,
-    replication checking is off where vma does not exist — the two
-    configurations under which the same shard-level program is valid on
-    both generations.
-    """
-    if HAS_NATIVE_SHARD_MAP:
-        if check is not None:
-            kwargs.setdefault("check_vma", check)
-        return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs, **kwargs)
-    kwargs.pop("check_vma", None)
-    return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs,
-                           check_rep=False if check is None else check,
-                           **kwargs)
-
-
-if HAS_VMA:
-    def pvary(x, axis_names: Sequence[str]):
-        """Promote ``x`` to vary over ``axis_names`` (no-op on 0.4.x)."""
-        return lax.pvary(x, tuple(axis_names))
-
-    def vma_of(x) -> frozenset:
-        """The set of manual axes ``x`` varies over (empty on 0.4.x)."""
-        return frozenset(jax.typeof(x).vma)
-else:
-    def pvary(x, axis_names: Sequence[str]):
-        """Promote ``x`` to vary over ``axis_names`` (no-op on 0.4.x)."""
-        del axis_names
-        return x
-
-    def vma_of(x) -> frozenset:
-        """The set of manual axes ``x`` varies over (empty on 0.4.x)."""
-        del x
-        return frozenset()
+def vma_of(x) -> frozenset:
+    """The set of manual axes ``x`` varies over."""
+    return frozenset(jax.typeof(x).vma)
 
 
 def vary(x, axis_names: Sequence[str]):
-    """pvary only over the axes ``x`` is not already varying over."""
+    """Cast ``x`` to varying over the axes of ``axis_names`` it does not
+    already vary over."""
     missing = tuple(a for a in axis_names if a not in vma_of(x))
-    return pvary(x, missing) if missing else x
-
-
-# lax.psum_scatter exists on both generations; re-exported so callers
-# have a single import site for the manual-collective surface.
-psum_scatter = lax.psum_scatter
-
-if hasattr(lax, "axis_size"):
-    axis_size = lax.axis_size
-else:  # 0.4.x: psum of a concrete 1 is folded to the static axis size
-    def axis_size(axis_name) -> int:
-        return int(lax.psum(1, axis_name))
+    return lax.pcast(x, missing, to="varying") if missing else x

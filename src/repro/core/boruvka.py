@@ -109,6 +109,8 @@ def boruvka_round(u: jax.Array, v: jax.Array, w: jax.Array,
                   ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """One Borůvka round on dense labels. Returns (labels', mst', changed)."""
     m = u.shape[0]
+    if m == 0:  # no edge to choose, and nothing to gather the choice from
+        return labels, mst, jnp.array(False)
     ru = labels[u]
     rv = labels[v]
     _, emin = min_edge_per_component(ru, rv, w, n)

@@ -209,9 +209,12 @@ def _shared_vertex_root_mask(u: jax.Array, valid: jax.Array, n: int,
     has = cnt > 0
     first = jnp.where(has, u[0], -1)
     last = jnp.where(has, u[jnp.clip(cnt - 1, 0, u.shape[0] - 1)], -2)
-    firsts = lax.all_gather(first, axes, tiled=False).reshape(-1)
-    lasts = lax.all_gather(last, axes, tiled=False).reshape(-1)
-    p = firsts.shape[0]
+    # psum of one-hot rows: a [p] gather whose result is replicated
+    # (invariant), so callers may return it with a replicated out_spec
+    p = compat.axis_size(axes)
+    onehot = lax.axis_index(axes) == jnp.arange(p)
+    firsts = lax.psum(jnp.where(onehot, first, 0), axes)
+    lasts = lax.psum(jnp.where(onehot, last, 0), axes)
     # boundary j|j+1 is shared when shard j's last src == shard j+1's first
     shared = (lasts[:-1] == firsts[1:]) & (lasts[:-1] >= 0)
     shared_ids = jnp.where(shared, lasts[:-1], n)  # n -> dropped

@@ -327,12 +327,8 @@ def _vsorted_lookup(table: jax.Array, vidx: VIndex, valid: jax.Array,
     out per run and back to original slot order through ``vidx.rank``.
 
     This gathers/scatters through the derived run/rank arrays rather
-    than ``vidx.perm`` directly.  (Historical: an early JAX 0.4.x CPU
-    backend miscompiled a closed-over ``argsort`` permutation gathered
-    inside a ``lax.while_loop`` body; the pinned 0.4.37 no longer
-    reproduces it — tests/test_serve_msf.py pins the repro pattern —
-    and the run/rank form is kept because it is also what the
-    coalesced-reply fan-out needs.)
+    than ``vidx.perm`` directly: the run/rank form is what the
+    coalesced-reply fan-out needs.
     """
     names = tuple(axes)
     head, head_idx, run_id = vidx.runs
@@ -739,8 +735,7 @@ def _sharded_preprocess(u, v, w, eid, valid, n: int, vps: int,
 
 
 def _owner_scatter_min(comp, wc, ec, oc, okc, base, vps: int,
-                       use_pallas: bool = False,
-                       names: Tuple[str, ...] = ()):
+                       use_pallas: bool = False):
     """Owner-side (w, eid)-ordered scatter-min over owned component slots.
 
     Shared by both MINEDGES variants so the tie-break discipline cannot
@@ -762,9 +757,6 @@ def _owner_scatter_min(comp, wc, ec, oc, okc, base, vps: int,
         # real row, the kernel's ok mask drops them before they touch it
         idx = jnp.where(okc, comp - base, 0)
         wt, et, pt, _ = owner_scatter_min(idx, wc, ec, oc, oc, okc, vps)
-        wt = compat.vary(wt, names)
-        et = compat.vary(et, names)
-        pt = compat.vary(pt, names)
         wmin = jnp.concatenate([wt.astype(wc.dtype),
                                 jnp.full((1,), jnp.inf, wc.dtype)])
         emin = jnp.concatenate([et, jnp.full((1,), ESENT, jnp.int32)])
@@ -818,8 +810,7 @@ def _sharded_minedges(ru, rv, wk, eid, alive, vps: int, capacity: int,
     oc = jnp.concatenate([ou, ov])
     okc = jnp.concatenate([oku, okv])
     has, other, is_win, _ = _owner_scatter_min(comp, wc, ec, oc, okc,
-                                               base, vps, use_pallas,
-                                               names)
+                                               base, vps, use_pallas)
     # confirm winners to the submitting slots (both exchanges carry the
     # same (w, eid) for the two copies of an undirected edge, so a slot
     # wins iff either of its endpoint components chose it)
@@ -883,10 +874,7 @@ def _sharded_minedges_src(ru, rv, wk, eid, alive, runs, vps: int,
         # is exactly isfinite(wtbl).
         wtbl, etbl, otbl, ctbl = owner_scatter_min(
             run_id, wk, eid, rv, ru, alive, L)
-        wtbl = compat.vary(wtbl.astype(wk.dtype), names)
-        etbl = compat.vary(etbl, names)
-        otbl = compat.vary(otbl, names)
-        ctbl = compat.vary(ctbl, names)
+        wtbl = wtbl.astype(wk.dtype)
         at_min = alive & (wk == wtbl[run_id])
         loc_win = at_min & (eid == etbl[run_id])
         send = head & jnp.isfinite(wtbl)[run_id]
@@ -915,8 +903,7 @@ def _sharded_minedges_src(ru, rv, wk, eid, alive, runs, vps: int,
     comp, w_, e_, o_ = (x.reshape(-1) for x in ex.recv)
     okc = ex.recv_ok.reshape(-1)
     has, other, is_win, off = _owner_scatter_min(comp, w_, e_, o_, okc,
-                                                 base, vps, use_pallas,
-                                                 names)
+                                                 base, vps, use_pallas)
     return has, other, is_win, off, ex, loc_win, head_idx
 
 
@@ -3029,7 +3016,7 @@ def distributed_sharded_msf(graph: DistGraph, n: int,
     ``pallas_minedges=True`` (ISSUE 8) routes both MINEDGES reductions
     — the pre-routing per-run combine and the owner-side scatter-min —
     through the fused ``kernels/segmin`` Pallas kernel
-    (``owner_scatter_min``: compiled on TPU, interpreted elsewhere via
+    (``owner_scatter_min``: compiled on TPU, interpreted on the CPU via
     ``default_interpret``) instead of the jnp scatter path; results are
     bit-identical (tests/test_kernels_fuzz.py pins the kernel, the
     equivalence matrix pins the engine) and the jnp path stays the

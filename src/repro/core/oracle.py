@@ -51,6 +51,40 @@ def kruskal(u: np.ndarray, v: np.ndarray, w: np.ndarray, n: int
     return mask, total
 
 
+def kruskal_fast(u: np.ndarray, v: np.ndarray, w: np.ndarray, n: int
+                 ) -> np.ndarray:
+    """The same (w, eid)-ordered MSF as ``kruskal``, vectorised.
+
+    Edges are ranked by ``(w, eid)``; of each vertex pair only the
+    min-rank edge can be in the forest, and the forest of the unique
+    ranks is scipy's minimum spanning tree.  Independent of the engines
+    and exact for graphs too large for the Python loop.  Returns the
+    mask over input edges.
+    """
+    from scipy.sparse import coo_matrix
+    from scipy.sparse.csgraph import minimum_spanning_tree
+
+    m = len(u)
+    order = np.lexsort((np.arange(m), w))
+    rank = np.empty(m, np.int64)
+    rank[order] = np.arange(1, m + 1)  # 1-based: scipy reads 0 as no edge
+    a = np.minimum(u, v).astype(np.int64)
+    b = np.maximum(u, v).astype(np.int64)
+    live = np.nonzero(np.isfinite(w) & (a != b))[0]
+    key = a[live] * n + b[live]
+    by_pair = live[np.lexsort((rank[live], key))]
+    pair = a[by_pair] * n + b[by_pair]
+    first = np.ones(len(by_pair), bool)
+    first[1:] = pair[1:] != pair[:-1]
+    cand = by_pair[first]
+    g = coo_matrix((rank[cand].astype(np.float64), (a[cand], b[cand])),
+                   shape=(n, n)).tocsr()
+    tree = minimum_spanning_tree(g).tocoo()
+    mask = np.zeros(m, bool)
+    mask[order[tree.data.astype(np.int64) - 1]] = True
+    return mask
+
+
 def msf_weight(u: np.ndarray, v: np.ndarray, w: np.ndarray, n: int) -> float:
     return kruskal(u, v, w, n)[1]
 

@@ -75,7 +75,7 @@ def min_edges_dense(seg: jax.Array, w: jax.Array, eid: jax.Array,
     ``use_pallas=False`` routes through the pure-jnp oracle (same
     contract), which is what the CPU test/bench path uses by default.
     ``interpret=None`` resolves backend-aware (compiled on TPU,
-    interpreted elsewhere).
+    interpreted on the CPU).
     """
     if use_pallas:
         cw, ce = segmin_candidates(seg, w, eid, alive, block=block,

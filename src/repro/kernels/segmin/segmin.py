@@ -43,14 +43,26 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.sharding import PartitionSpec as P
+
+from repro import compat
 
 EID_SENTINEL = 2 ** 30
 
 
 def default_interpret() -> bool:
     """Backend-aware Pallas mode: compile on the TPU the kernels target,
-    interpret everywhere else (CPU tests/benches, GPU fallback)."""
-    return jax.default_backend() != "tpu"
+    interpret on the CPU (tests).  Any other backend raises: the kernels
+    are written for Mosaic, and interpreting them there would hide the
+    device."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise NotImplementedError(
+        f"the Pallas kernels target the TPU (compiled) and the CPU "
+        f"(interpreted); backend {backend!r} is neither")
 
 
 def _segmin_kernel(seg_ref, w_ref, eid_ref, alive_ref, cw_ref, ce_ref,
@@ -91,7 +103,7 @@ def _segmin_kernel(seg_ref, w_ref, eid_ref, alive_ref, cw_ref, ce_ref,
     ce_ref[...] = jnp.where(is_last, val_e, sent)
 
 
-def _scatter_min_kernel(idx_ref, w_ref, eid_ref, p1_ref, p2_ref, ok_ref,
+def _scatter_min_kernel(idx_ref, w_ref, eid_ref, p1_ref, p2_ref,
                         wt_ref, et_ref, p1t_ref, p2t_ref, *,
                         out_block: int, block: int):
     """Fused min-semiring scatter: one grid step folds one candidate
@@ -99,25 +111,26 @@ def _scatter_min_kernel(idx_ref, w_ref, eid_ref, p1_ref, p2_ref, ok_ref,
 
     Grid is (out tiles, candidate blocks) with the candidate dimension
     innermost, so the output tile persists in VMEM across the whole
-    candidate sweep (initialised at the first step).  Per step the
-    block builds the [out_block, block] one-hot hit matrix — the
+    candidate sweep (initialised at the first step).  Candidates arrive
+    as ``(1, block)`` lane rows and the tile is an ``(out_block, 1)``
+    column, so the [out_block, block] one-hot hit matrix — the
     TPU-native replacement for the scatter the jnp path pays five times
-    — and reduces it to the tile's block-local (min w, min eid among
-    w-ties, payload at the (w, eid) winner); a lexicographic combine
-    then folds the block triple into the accumulator.  Payload-at-winner
-    is reduced with max, which is exact because candidates tied on the
-    full (w, eid) key carry identical payloads (both directed copies of
-    an undirected edge ship the same eid and the same opposing
-    component) — the same argument the jnp path's ``.at[].max`` relies
-    on.
+    — is a broadcast compare, reduced along lanes to the tile's
+    block-local (min w, min eid among w-ties, payload at the (w, eid)
+    winner); a lexicographic combine then folds the block triple into
+    the accumulator.  Payload-at-winner is reduced with max, which is
+    exact because candidates tied on the full (w, eid) key carry
+    identical payloads (both directed copies of an undirected edge ship
+    the same eid and the same opposing component) — the same argument
+    the jnp path's ``.at[].max`` relies on.  Lanes with ``idx < 0`` are
+    gated off (they never equal a tile row).
 
-    A sparse-band guard skips candidate blocks whose (ok-gated) index
-    range cannot touch this tile: for the pre-routing per-run combine
-    the index column (``run_id``) is non-decreasing, so each candidate
-    block intersects O(1) tiles and the sweep degenerates to the
-    band — the fused equivalent of the segmented scan's contiguity
-    exploitation.  Owner-side (unsorted ``comp - base``) it simply
-    never fires.
+    A sparse-band guard skips candidate blocks whose live index range
+    cannot touch this tile: for the pre-routing per-run combine the
+    index row (``run_id``) is non-decreasing, so each candidate block
+    intersects O(1) tiles and the compute degenerates to the band.
+    Owner-side (unsorted ``comp - base``) it simply never fires.  The
+    grid itself stays (tiles x blocks) either way.
     """
     c = pl.program_id(1)
 
@@ -126,16 +139,15 @@ def _scatter_min_kernel(idx_ref, w_ref, eid_ref, p1_ref, p2_ref, ok_ref,
 
     @pl.when(c == 0)
     def _init():
-        wt_ref[...] = jnp.full((out_block,), inf, jnp.float32)
-        et_ref[...] = jnp.full((out_block,), sent, jnp.int32)
-        p1t_ref[...] = jnp.full((out_block,), -1, jnp.int32)
-        p2t_ref[...] = jnp.full((out_block,), -1, jnp.int32)
+        wt_ref[...] = jnp.full((out_block, 1), inf, jnp.float32)
+        et_ref[...] = jnp.full((out_block, 1), sent, jnp.int32)
+        p1t_ref[...] = jnp.full((out_block, 1), -1, jnp.int32)
+        p2t_ref[...] = jnp.full((out_block, 1), -1, jnp.int32)
 
     idx = idx_ref[...]
-    ok = ok_ref[...] != 0
     row0 = pl.program_id(0) * out_block
-    lo = jnp.min(jnp.where(ok, idx, jnp.int32(2 ** 31 - 1)))
-    hi = jnp.max(jnp.where(ok, idx, jnp.int32(-1)))
+    lo = jnp.min(jnp.where(idx >= 0, idx, jnp.int32(2 ** 31 - 1)))
+    hi = jnp.max(idx)
 
     @pl.when((lo < row0 + out_block) & (hi >= row0))
     def _accumulate():
@@ -143,14 +155,16 @@ def _scatter_min_kernel(idx_ref, w_ref, eid_ref, p1_ref, p2_ref, ok_ref,
         eid = eid_ref[...]
         rows = row0 + jax.lax.broadcasted_iota(jnp.int32,
                                                (out_block, block), 0)
-        hit = (idx[None, :] == rows) & ok[None, :]
-        wv = jnp.where(hit, w[None, :], inf)
-        wb = jnp.min(wv, axis=1)
-        tie = hit & (wv == wb[:, None])
-        eb = jnp.min(jnp.where(tie, eid[None, :], sent), axis=1)
-        winm = tie & (eid[None, :] == eb[:, None])
-        p1b = jnp.max(jnp.where(winm, p1_ref[...][None, :], -1), axis=1)
-        p2b = jnp.max(jnp.where(winm, p2_ref[...][None, :], -1), axis=1)
+        hit = idx == rows
+        wv = jnp.where(hit, w, inf)
+        wb = jnp.min(wv, axis=1, keepdims=True)
+        tie = hit & (wv == wb)
+        eb = jnp.min(jnp.where(tie, eid, sent), axis=1, keepdims=True)
+        winm = tie & (eid == eb)
+        p1b = jnp.max(jnp.where(winm, p1_ref[...], -1), axis=1,
+                      keepdims=True)
+        p2b = jnp.max(jnp.where(winm, p2_ref[...], -1), axis=1,
+                      keepdims=True)
 
         cw, ce = wt_ref[...], et_ref[...]
         better = wb < cw
@@ -197,45 +211,69 @@ def owner_scatter_min(idx: jax.Array, w: jax.Array, eid: jax.Array,
     to the jnp ``.at[].min``/``.at[].max`` path for any candidate order
     (min/max are associative-commutative and payloads are constant
     across exact (w, eid) ties).  ``ok=False`` lanes never contribute —
-    their ``idx`` may be garbage.  Same block/``interpret`` discipline
-    as ``segmin_candidates``.
+    their ``idx`` may be garbage.  The grid has
+    ``ceil(size / out_block) * ceil(L / block)`` steps, so the cost
+    grows with ``size * L``.  Same block/``interpret`` discipline as
+    ``segmin_candidates``.
     """
     if interpret is None:
         interpret = default_interpret()
+    # the manual axes the inputs vary over (none outside shard_map): the
+    # kernel's outputs must declare them
+    vma = frozenset().union(*map(compat.vma_of,
+                                 (idx, w, eid, pay1, pay2, ok)))
+    impl = functools.partial(_owner_scatter_min, size=size, block=block,
+                             out_block=out_block, interpret=interpret,
+                             vma=vma)
+    if interpret and vma:
+        # The HLO interpreter evaluates the kernel body with shard_map's
+        # varying-axes checks on, though the body was traced with them
+        # off, and rejects it.  Interpret it inside a shard_map over no
+        # further axes with the checks off: the values stay per shard.
+        impl = jax.shard_map(impl, in_specs=P(), out_specs=P(),
+                             axis_names=frozenset(), check_vma=False)
+    return tuple(compat.vary(x, tuple(vma))
+                 for x in impl(idx, w, eid, pay1, pay2, ok))
+
+
+def _owner_scatter_min(idx, w, eid, pay1, pay2, ok, *, size: int,
+                       block: int, out_block: int, interpret,
+                       vma: frozenset):
     L = idx.shape[0]
     if L == 0 or size == 0:
         return (jnp.full((size,), jnp.inf, jnp.float32),
                 jnp.full((size,), EID_SENTINEL, jnp.int32),
                 jnp.full((size,), -1, jnp.int32),
                 jnp.full((size,), -1, jnp.int32))
-    block = min(block, max(L, 8))
-    out_block = min(out_block, max(size, 8))
+    # lane blocks are multiples of 128 and sublane tiles multiples of 8,
+    # or the whole (padded) axis: the tiling Mosaic accepts
+    block = min(block, L + (-L) % 128)
+    out_block = min(out_block, size + (-size) % 8)
+    idx = jnp.where(ok, idx, -1)  # gate folded into the index row
     pad = (-L) % block
     if pad:
-        idx = jnp.concatenate([idx, jnp.zeros((pad,), idx.dtype)])
+        idx = jnp.concatenate([idx, jnp.full((pad,), -1, idx.dtype)])
         w = jnp.concatenate([w, jnp.full((pad,), jnp.inf, w.dtype)])
         eid = jnp.concatenate([eid, jnp.full((pad,), EID_SENTINEL,
                                              eid.dtype)])
         pay1 = jnp.concatenate([pay1, jnp.full((pad,), -1, pay1.dtype)])
         pay2 = jnp.concatenate([pay2, jnp.full((pad,), -1, pay2.dtype)])
-        ok = jnp.concatenate([ok, jnp.zeros((pad,), ok.dtype)])
     sp = size + ((-size) % out_block)
     grid = (sp // out_block, idx.shape[0] // block)
-    cspec = pl.BlockSpec((block,), lambda o, c: (c,))
-    ospec = pl.BlockSpec((out_block,), lambda o, c: (o,))
-    wt, et, p1t, p2t = pl.pallas_call(
+    cspec = pl.BlockSpec((1, block), lambda o, c: (0, c))
+    ospec = pl.BlockSpec((out_block, 1), lambda o, c: (o, 0))
+    out_shape = [jax.ShapeDtypeStruct((sp, 1), dt, vma=vma)
+                 for dt in (jnp.float32, jnp.int32, jnp.int32, jnp.int32)]
+    outs = pl.pallas_call(
         functools.partial(_scatter_min_kernel, out_block=out_block,
                           block=block),
         grid=grid,
-        in_specs=[cspec] * 6,
+        in_specs=[cspec] * 5,
         out_specs=[ospec] * 4,
-        out_shape=[jax.ShapeDtypeStruct((sp,), jnp.float32),
-                   jax.ShapeDtypeStruct((sp,), jnp.int32),
-                   jax.ShapeDtypeStruct((sp,), jnp.int32),
-                   jax.ShapeDtypeStruct((sp,), jnp.int32)],
+        out_shape=out_shape,
         interpret=interpret,
-    )(idx, w, eid, pay1, pay2, ok.astype(jnp.int8))
-    return wt[:size], et[:size], p1t[:size], p2t[:size]
+    )(*(x[None, :] for x in (idx, w, eid, pay1, pay2)))
+    return tuple(x[:size, 0] for x in outs)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
@@ -246,7 +284,7 @@ def segmin_candidates(seg: jax.Array, w: jax.Array, eid: jax.Array,
 
     Padding entries must carry alive=False (any seg value).  Returns
     (cand_w f32 [M], cand_eid i32 [M]).  ``interpret=None`` resolves
-    via ``default_interpret()`` (compiled on TPU, interpreted elsewhere).
+    via ``default_interpret()`` (compiled on TPU, interpreted on the CPU).
     """
     if interpret is None:
         interpret = default_interpret()

@@ -61,7 +61,10 @@ def main() -> None:
     import jax
     import numpy as np
     from jax.sharding import Mesh
+    from repro.compile_cache import place_compile_cache
     from repro.serve.msf_gateway import MSFGateway
+
+    place_compile_cache()
 
     if args.smoke:
         args.requests = min(args.requests, 24)

@@ -1,5 +1,5 @@
-"""repro.compat: the JAX 0.4.x / >=0.6 bridge must expose one working
-surface on whichever generation is installed (EXPERIMENTS.md §Compat)."""
+"""repro.compat: the manual-collective surface (shard_map, vma helpers)
+on the installed JAX (EXPERIMENTS.md §Compat)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -10,24 +10,18 @@ from tests.helpers.subproc import run_multidevice
 
 
 def test_exports_present():
-    for name in ("shard_map", "pvary", "vma_of", "vary", "psum_scatter",
-                 "axis_size", "HAS_VMA", "HAS_NATIVE_SHARD_MAP"):
+    assert set(compat.__all__) == {"shard_map", "vma_of", "vary",
+                                   "axis_size"}
+    for name in compat.__all__:
         assert hasattr(compat, name), name
-    assert isinstance(compat.HAS_VMA, bool)
-    assert isinstance(compat.HAS_NATIVE_SHARD_MAP, bool)
-    # flags must reflect the installed generation, not hardcode one
-    assert compat.HAS_NATIVE_SHARD_MAP == hasattr(jax, "shard_map")
-    assert compat.HAS_VMA == (hasattr(jax.lax, "pvary")
-                              and hasattr(jax, "typeof"))
+    assert compat.shard_map is jax.shard_map
+    assert compat.axis_size is jax.lax.axis_size
 
 
 def test_pvary_vary_outside_shard_map():
     x = jnp.arange(4.0)
-    # with no vma system, pvary/vary must be exact identities
-    if not compat.HAS_VMA:
-        assert compat.pvary(x, ("a", "b")) is x
-        assert compat.vary(x, ("a",)) is x
-    # empty axis tuple is an identity on every generation
+    # an empty axis tuple is an identity, and a value outside shard_map
+    # varies over no manual axis
     assert compat.vary(x, ()) is x
     assert compat.vma_of(x) == frozenset()
 
@@ -54,12 +48,12 @@ mesh = Mesh(np.array(jax.devices()), ("x",))
 p = 4
 
 def body(a):
-    # axis_size: static int on 0.4.x, usable as a shape/constant
+    # axis_size is a static int, usable as a shape/constant
     assert compat.axis_size("x") == p
     a = compat.vary(a, ("x",))
     # psum_scatter over equal slices == slice of psum
     full = jax.lax.psum(a, ("x",))
-    scat = compat.psum_scatter(a, "x", scatter_dimension=0, tiled=True)
+    scat = jax.lax.psum_scatter(a, "x", scatter_dimension=0, tiled=True)
     i = jax.lax.axis_index("x")
     want = jax.lax.dynamic_slice_in_dim(full, i * (a.shape[0] // p),
                                         a.shape[0] // p)

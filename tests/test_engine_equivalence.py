@@ -94,75 +94,6 @@ def test_distributed_engines_match_oracle():
     assert "OK" in out
 
 
-# the sharded engine's ISSUE 2 communication levers, each toggled alone
-# plus all together, must keep the MSF edge set bit-identical to the
-# oracle on the adversarial families (heavy ties exercise the (w, eid)
-# tie-break through the src-only owner-side marking; disconnected
-# exercises the dead-edge retirement's termination)
-SHARDED_FLAGS = inspect.getsource(graph_families) + """
-from jax.sharding import Mesh
-from repro.core import oracle
-from repro.core.graph import from_numpy
-from repro.core.mst import minimum_spanning_forest
-
-mesh = Mesh(np.array(jax.devices()), ("data",))
-OFF = dict(local_preprocessing=False, coalesce=False, src_only=False,
-           adaptive_doubling=False, shrink_capacities=False,
-           ghost_cache=False, relabel_skip=False)
-COMBOS = [
-    dict(OFF),                                           # the PR 1 baseline
-    dict(OFF, local_preprocessing=True),
-    dict(OFF, coalesce=True),            # incl. the v-sorted index
-    dict(OFF, coalesce=True, vsorted_index=False),  # PR 3 slot-order v
-    dict(OFF, src_only=True),
-    dict(OFF, adaptive_doubling=True),
-    dict(OFF, shrink_capacities=True),   # shrinking schedule alone
-    dict(OFF, relabel_skip=True),        # settled-vertex RELABEL skip
-    # the ISSUE 4 ghost_cache x coalesce x shrink_capacities sub-matrix
-    # (the cache replaces the endpoint lookups, so each pairing takes a
-    # genuinely different code path through _round_body)
-    dict(OFF, ghost_cache=True),
-    dict(OFF, ghost_cache=True, coalesce=True),
-    dict(OFF, ghost_cache=True, shrink_capacities=True),
-    dict(OFF, ghost_cache=True, coalesce=True, shrink_capacities=True),
-    dict(ghost_cache=False, vsorted_index=False),  # the PR 3 optimized
-    dict(ghost_cache=False),             # all levers minus the cache
-    dict(shrink_capacities=False),       # all levers, flat capacities
-    dict(),                              # everything incl. the schedule
-    # the ISSUE 8 pallas_minedges lever: the fused kernel must be
-    # bit-identical through every MINEDGES code path — the 2-exchange
-    # baseline, the src-only per-run combine, ghost/vsorted reads, the
-    # shrinking schedule, and the all-on engine
-    dict(OFF, pallas_minedges=True),                     # 2-exchange kernel
-    dict(OFF, src_only=True, pallas_minedges=True),      # fused combine
-    dict(OFF, ghost_cache=True, coalesce=True, pallas_minedges=True),
-    dict(shrink_capacities=False, pallas_minedges=True),  # flat + kernel
-    dict(ghost_cache=False, vsorted_index=False, pallas_minedges=True),
-    dict(pallas_minedges=True),          # everything through the kernel
-]
-
-for fam in ("random", "clustered", "dup_weights", "disconnected"):
-    u, v, w, n = FAMILIES[fam](0)
-    edges = from_numpy(u, v, w, n)
-    kmask, kweight = oracle.kruskal(u, v, w, n)
-    for combo in COMBOS:
-        mask, wt = minimum_spanning_forest(
-            edges, algorithm="boruvka", engine="distributed_sharded",
-            mesh=mesh, **combo)
-        mk = np.asarray(mask)
-        assert np.array_equal(np.nonzero(mk)[0], np.nonzero(kmask)[0]), (
-            fam, combo, "edge set differs from oracle")
-        assert abs(float(wt) - kweight) < 1e-3 * max(1.0, kweight), (
-            fam, combo, float(wt), kweight)
-print("OK")
-"""
-
-
-def test_sharded_optimization_flags_match_oracle():
-    out = run_multidevice(SHARDED_FLAGS, ndev=8, timeout=1800)
-    assert "OK" in out
-
-
 # plan measured with the kernel lever, replayed strictly (replan=False)
 # through the Python-unrolled executor with the ISSUE 7 self-verifier on:
 # pins (a) the lever survives the RoundPlan round-trip, (b) replay is

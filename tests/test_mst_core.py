@@ -147,3 +147,25 @@ def test_property_unique_msf_edges_match(n, m, seed):
         got = np.sort(w[np.asarray(mask)])
         exp = np.sort(w[emask])
         assert np.allclose(got, exp)
+
+
+@pytest.mark.parametrize("family", ["random", "ties_loops_inf", "rgg2d"])
+def test_kruskal_fast_matches_kruskal(family):
+    """The vectorised oracle picks the loop oracle's exact (w, eid) edge
+    set: parallel edges, self-loops, heavy weight ties and +inf padding."""
+    for seed in range(40 if family != "rgg2d" else 2):
+        rng = np.random.default_rng(seed)
+        if family == "rgg2d":
+            u, v, w, n = generators.rgg2d(2048, 8.0, seed)
+        else:
+            n = int(rng.integers(1, 40))
+            m = int(rng.integers(0, 150))
+            u = rng.integers(0, n, m).astype(np.int32)
+            v = rng.integers(0, n, m).astype(np.int32)
+            if family == "random":
+                w = rng.uniform(1, 255, m).astype(np.float32)
+            else:
+                w = rng.integers(1, 6, m).astype(np.float32)
+                w[rng.random(m) < 0.1] = np.inf
+        want, _ = oracle.kruskal(u, v, w, n)
+        assert np.array_equal(oracle.kruskal_fast(u, v, w, n), want), seed

@@ -3,9 +3,7 @@ synthetic plans (in-process), and the gateway's serving contract on 8
 virtual devices (subprocess) — oracle bit-identity of every served
 forest, hit/miss/evict accounting, the replan fallback for traffic
 whose shapes match a cached plan but whose structure overflows it, and
-the drift-triggered plan refresh.  Also the minimal repro for the
-historical JAX 0.4.x CPU while_loop/argsort closure miscompile
-(xfail on the affected generation; the pinned 0.4.37 passes)."""
+the drift-triggered plan refresh."""
 import math
 
 import numpy as np
@@ -390,64 +388,3 @@ def test_synthetic_plan_calibration_multidevice():
     out = run_multidevice(CALIBRATION, ndev=8, timeout=1800)
     assert "OK" in out
 
-
-# -- the historical while_loop/argsort closure miscompile ------------------
-
-MISCOMPILE = """
-from jax import lax
-from jax.sharding import Mesh, PartitionSpec as P
-
-p, L = 8, 64
-mesh = Mesh(np.array(jax.devices()), ("data",))
-rng = np.random.default_rng(0)
-keys = rng.integers(0, 1000, (p, L)).astype(np.int32)
-vals = rng.integers(0, 1000, (p, L)).astype(np.int32)
-
-def shard_fn(k, x):
-    # the hazard pattern once noted on _vsorted_lookup: an argsort
-    # permutation computed OUTSIDE a lax.while_loop, closed over, and
-    # consumed by gathers/scatters INSIDE the body, under shard_map
-    # with a routed exchange in the loop
-    perm = jnp.argsort(k[0], stable=True)
-    inv = jnp.zeros(L, jnp.int32).at[perm].set(
-        jnp.arange(L, dtype=jnp.int32))
-    expect = x[0][perm]
-
-    def body(c):
-        i, acc = c
-        y = x[0][perm]
-        y = lax.all_to_all(y.reshape(p, L // p), "data", 0, 0).reshape(L)
-        y = lax.all_to_all(y.reshape(p, L // p), "data", 0, 0).reshape(L)
-        z = jnp.zeros(L, jnp.int32).at[perm].add(y[inv][perm])
-        return i + 1, acc + y + 0 * z[0]
-
-    _, acc = lax.while_loop(lambda c: c[0] < 3, body,
-                            (jnp.int32(0), jnp.zeros(L, jnp.int32)))
-    return (acc - 3 * expect)[None]
-
-fn = jax.jit(shard_map(shard_fn, mesh=mesh,
-                       in_specs=(P("data"), P("data")),
-                       out_specs=P("data")))
-diff = int(np.abs(np.asarray(fn(keys, vals))).max())
-assert diff == 0, f"closure-permutation gather corrupted {diff}"
-print("OK")
-"""
-
-
-def _affected_generation() -> bool:
-    import jax
-    try:
-        ver = tuple(int(x) for x in jax.__version__.split(".")[:3])
-    except ValueError:
-        return False
-    return (0, 4, 0) <= ver < (0, 4, 37)
-
-
-@pytest.mark.xfail(condition=_affected_generation(), strict=False,
-                   reason="JAX 0.4.x CPU before 0.4.37 miscompiled "
-                          "closed-over argsort perms gathered inside "
-                          "while_loop bodies (historical note on "
-                          "_vsorted_lookup); fixed by the pinned 0.4.37")
-def test_while_loop_argsort_closure_repro():
-    out = run_multidevice(MISCOMPILE, ndev=8, timeout=900)
-    assert "OK" in out
