@@ -38,7 +38,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro import compat
+from repro import compat, obs
 from repro.comm import faults
 from repro.comm.grid_alltoall import all_to_all_nd
 
@@ -179,6 +179,7 @@ def _group_positions(dest: jax.Array, valid: jax.Array, p: int) -> jax.Array:
     return jnp.zeros((L,), jnp.int32).at[order].set(pos_sorted)
 
 
+@obs.scope("exchange")
 def routed_exchange(payload, dest: jax.Array, valid: jax.Array,
                     capacity: int, axis_names: Sequence[str],
                     schedule: str = "grid",
@@ -254,6 +255,7 @@ def routed_exchange(payload, dest: jax.Array, valid: jax.Array,
     return ExchangeResult(recv, recv_ok, ok, dest, pos, overflow, stats)
 
 
+@obs.scope("exchange")
 def reply(ex: ExchangeResult, answers, axis_names: Sequence[str],
           schedule: str = "grid", stats: Optional[ExchangeStats] = None):
     """Route per-slot ``answers`` ([p, C, ...], aligned with ``ex.recv``)

@@ -32,6 +32,7 @@ from typing import NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core.graph import EdgeList
 
 
@@ -40,6 +41,7 @@ class BoruvkaState(NamedTuple):
     mst: jax.Array       # bool  [m] chosen MSF edges
     changed: jax.Array   # bool  []  did the last round contract anything
     rounds: jax.Array    # int32 []  rounds executed
+    live: jax.Array      # int32 []  alive edge slots summed over rounds
 
 
 def _doubling_iters(n: int) -> int:
@@ -55,6 +57,15 @@ def min_edge_per_component(ru: jax.Array, rv: jax.Array, w: jax.Array,
     index of the lexicographically-(w, idx)-smallest achieving edge.
     ``emin == m`` (sentinel) where a component has no alive incident edge.
     """
+    wmin, emin, _ = _min_edges(ru, rv, w, n)
+    return wmin, emin
+
+
+@obs.scope("minedges")
+def _min_edges(ru: jax.Array, rv: jax.Array, w: jax.Array, n: int
+               ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """``min_edge_per_component`` and the count of its candidate slots
+    (alive, finite weight; int32 [])."""
     m = w.shape[0]
     alive = ru != rv
     wk = jnp.where(alive & jnp.isfinite(w), w, jnp.inf)
@@ -68,7 +79,9 @@ def min_edge_per_component(ru: jax.Array, rv: jax.Array, w: jax.Array,
     emin = jnp.full((n,), sent, jnp.int32)
     emin = emin.at[ru].min(cand_u)
     emin = emin.at[rv].min(cand_v)
-    return wmin, emin
+    # wk is +inf or finite: counting `wk < inf` keeps XLA from fusing the
+    # count with the `isfinite(wk)` tests above and storing that mask
+    return wmin, emin, jnp.sum((wk < jnp.inf).astype(jnp.int32))
 
 
 def contract_components(emin: jax.Array, u: jax.Array, v: jax.Array,
@@ -84,22 +97,24 @@ def contract_components(emin: jax.Array, u: jax.Array, v: jax.Array,
     """
     m = u.shape[0]
     sent = jnp.int32(m)
-    has = emin < sent
-    ce = jnp.clip(emin, 0, m - 1)
-    cids = jnp.arange(n, dtype=jnp.int32)
-    cu = labels[u[ce]]
-    cv = labels[v[ce]]
-    other = cu + cv - cids  # the endpoint-component that is not `cids`
-    parent = jnp.where(has, other, cids)
-    if root_mask is not None:
-        parent = jnp.where(root_mask, cids, parent)
-    # Break 2-cycles: the smaller label of the pair becomes the root.
-    gp = parent[parent]
-    parent = jnp.where((gp == cids) & (cids < parent), cids, parent)
+    with obs.scope("contract"):
+        has = emin < sent
+        ce = jnp.clip(emin, 0, m - 1)
+        cids = jnp.arange(n, dtype=jnp.int32)
+        cu = labels[u[ce]]
+        cv = labels[v[ce]]
+        other = cu + cv - cids  # the endpoint-component that is not `cids`
+        parent = jnp.where(has, other, cids)
+        if root_mask is not None:
+            parent = jnp.where(root_mask, cids, parent)
+        # Break 2-cycles: the smaller label of the pair becomes the root.
+        gp = parent[parent]
+        parent = jnp.where((gp == cids) & (cids < parent), cids, parent)
     # Pointer doubling (Section IV-B / Chung & Condon).
     def double(_, p):
         return p[p]
-    roots = jax.lax.fori_loop(0, _doubling_iters(n), double, parent)
+    with obs.scope("doubling"):
+        roots = jax.lax.fori_loop(0, _doubling_iters(n), double, parent)
     return roots, has
 
 
@@ -108,17 +123,69 @@ def boruvka_round(u: jax.Array, v: jax.Array, w: jax.Array,
                   root_mask: Optional[jax.Array] = None,
                   ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """One Borůvka round on dense labels. Returns (labels', mst', changed)."""
+    return _boruvka_round_counted(u, v, w, labels, mst, n, root_mask)[:3]
+
+
+def _boruvka_round_counted(u: jax.Array, v: jax.Array, w: jax.Array,
+                           labels: jax.Array, mst: jax.Array, n: int,
+                           root_mask: Optional[jax.Array] = None,
+                           ) -> Tuple[jax.Array, jax.Array, jax.Array,
+                                      jax.Array]:
+    """``boruvka_round`` and the count of the edge slots MINEDGES could
+    choose: endpoints in different components, finite weight (int32 [])."""
     m = u.shape[0]
     if m == 0:  # no edge to choose, and nothing to gather the choice from
-        return labels, mst, jnp.array(False)
-    ru = labels[u]
-    rv = labels[v]
-    _, emin = min_edge_per_component(ru, rv, w, n)
+        return labels, mst, jnp.array(False), jnp.int32(0)
+    with obs.scope("label_gather"):
+        ru = labels[u]
+        rv = labels[v]
+    _, emin, live = _min_edges(ru, rv, w, n)
     roots, has = contract_components(emin, u, v, labels, n, root_mask)
-    ce = jnp.clip(emin, 0, m - 1)
-    mst_i = mst.astype(jnp.int32).at[ce].max(has.astype(jnp.int32))
-    labels = roots[labels]
-    return labels, mst_i.astype(bool), jnp.any(has)
+    with obs.scope("contract"):
+        ce = jnp.clip(emin, 0, m - 1)
+        mst_i = mst.astype(jnp.int32).at[ce].max(has.astype(jnp.int32))
+        labels = roots[labels]
+    return labels, mst_i.astype(bool), jnp.any(has), live
+
+
+def run_rounds(u: jax.Array, v: jax.Array, w: jax.Array,
+               labels: jax.Array, mst: jax.Array, n: int, max_rounds: int
+               ) -> BoruvkaState:
+    """Borůvka rounds until none contracts or ``max_rounds`` ran."""
+    init = BoruvkaState(labels=labels, mst=mst, changed=jnp.array(True),
+                        rounds=jnp.int32(0), live=jnp.int32(0))
+
+    def cond(s: BoruvkaState):
+        return s.changed & (s.rounds < max_rounds)
+
+    def body(s: BoruvkaState):
+        labels, mst, changed, live = _boruvka_round_counted(
+            u, v, w, s.labels, s.mst, n)
+        return BoruvkaState(labels, mst, changed, s.rounds + 1,
+                            s.live + live)
+
+    return jax.lax.while_loop(cond, body, init)
+
+
+def round_counters(state: BoruvkaState, m: int) -> dict:
+    """``obs.record``'s counters of one ``run_rounds`` over m slots."""
+    return {"rounds": state.rounds, "live_slots": state.live,
+            "slot_rounds": state.rounds * m}
+
+
+@partial(jax.jit, static_argnames=("n", "max_rounds"))
+def boruvka_msf_counted(u: jax.Array, v: jax.Array, w: jax.Array, n: int,
+                        max_rounds: Optional[int] = None
+                        ) -> Tuple[jax.Array, jax.Array, dict]:
+    """``boruvka_msf`` and its ``round_counters``."""
+    m = u.shape[0]
+    if max_rounds is None:
+        # each round at least halves #non-isolated components; a run over
+        # k edges touches <= 2k components.
+        max_rounds = max(1, math.ceil(math.log2(max(min(n, 2 * m), 2))) + 1)
+    final = run_rounds(u, v, w, jnp.arange(n, dtype=jnp.int32),
+                       jnp.zeros((m,), bool), n, max_rounds)
+    return final.mst, final.labels, round_counters(final, m)
 
 
 @partial(jax.jit, static_argnames=("n", "max_rounds"))
@@ -126,27 +193,8 @@ def boruvka_msf(u: jax.Array, v: jax.Array, w: jax.Array, n: int,
                 max_rounds: Optional[int] = None
                 ) -> Tuple[jax.Array, jax.Array]:
     """Jittable Borůvka. Returns (mst_mask[m] bool, labels[n] int32)."""
-    m = u.shape[0]
-    if max_rounds is None:
-        # each round at least halves #non-isolated components; a run over
-        # k edges touches <= 2k components.
-        max_rounds = max(1, math.ceil(math.log2(max(min(n, 2 * m), 2))) + 1)
-    init = BoruvkaState(
-        labels=jnp.arange(n, dtype=jnp.int32),
-        mst=jnp.zeros((m,), bool),
-        changed=jnp.array(True),
-        rounds=jnp.int32(0),
-    )
-
-    def cond(s: BoruvkaState):
-        return s.changed & (s.rounds < max_rounds)
-
-    def body(s: BoruvkaState):
-        labels, mst, changed = boruvka_round(u, v, w, s.labels, s.mst, n)
-        return BoruvkaState(labels, mst, changed, s.rounds + 1)
-
-    final = jax.lax.while_loop(cond, body, init)
-    return final.mst, final.labels
+    mst, labels, _ = boruvka_msf_counted(u, v, w, n, max_rounds)
+    return mst, labels
 
 
 def boruvka_msf_on(edges: EdgeList, max_rounds: Optional[int] = None

@@ -40,7 +40,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
+from repro import compat, obs
 from repro.core.graph import INVALID_W, CapacityError
 
 # "no chosen edge" sentinel in eid space, shared by every engine (and
@@ -93,6 +93,7 @@ class DistGraph(NamedTuple):
         return int(self.u.shape[0])
 
 
+@obs.building()
 def build_dist_graph(u: np.ndarray, v: np.ndarray, w: np.ndarray, n: int,
                      num_shards: int,
                      cap: Optional[int] = None) -> Tuple[DistGraph, int]:
@@ -110,13 +111,14 @@ def build_dist_graph(u: np.ndarray, v: np.ndarray, w: np.ndarray, n: int,
     slots carry ``INVALID_W`` like any other tail padding.
     """
     m = len(u)
-    eid = np.arange(m, dtype=np.int32)
-    du = np.concatenate([u, v]).astype(np.int64)
-    dv = np.concatenate([v, u]).astype(np.int64)
-    dw = np.concatenate([w, w]).astype(np.float32)
-    de = np.concatenate([eid, eid])
-    order = np.lexsort((dw, dv, du))
-    du, dv, dw, de = du[order], dv[order], dw[order], de[order]
+    with obs.span("build.sort"):
+        eid = np.arange(m, dtype=np.int32)
+        du = np.concatenate([u, v]).astype(np.int64)
+        dv = np.concatenate([v, u]).astype(np.int64)
+        dw = np.concatenate([w, w]).astype(np.float32)
+        de = np.concatenate([eid, eid])
+        order = np.lexsort((dw, dv, du))
+        du, dv, dw, de = du[order], dv[order], dw[order], de[order]
     dm = len(du)
     need = max(1, -(-dm // num_shards))
     if cap is None:
@@ -129,20 +131,21 @@ def build_dist_graph(u: np.ndarray, v: np.ndarray, w: np.ndarray, n: int,
             f"shard (m={m}, p={num_shards}; "
             f"{dm - cap * num_shards} directed copies would be silently "
             "dropped)", dropped=dm - cap * num_shards)
-    uu = np.zeros(num_shards * cap, np.int32)
-    vv = np.zeros(num_shards * cap, np.int32)
-    ww = np.full(num_shards * cap, INVALID_W, np.float32)
-    ee = np.zeros(num_shards * cap, np.int32)
-    for s in range(num_shards):
-        lo, hi = s * cap, min((s + 1) * cap, dm)
-        if hi > lo:
-            k = hi - lo
-            uu[s * cap: s * cap + k] = du[lo:hi]
-            vv[s * cap: s * cap + k] = dv[lo:hi]
-            ww[s * cap: s * cap + k] = dw[lo:hi]
-            ee[s * cap: s * cap + k] = de[lo:hi]
-    return DistGraph(jnp.asarray(uu), jnp.asarray(vv), jnp.asarray(ww),
-                     jnp.asarray(ee)), cap
+    with obs.span("build.pack"):
+        uu = np.zeros(num_shards * cap, np.int32)
+        vv = np.zeros(num_shards * cap, np.int32)
+        ww = np.full(num_shards * cap, INVALID_W, np.float32)
+        ee = np.zeros(num_shards * cap, np.int32)
+        for s in range(num_shards):
+            lo, hi = s * cap, min((s + 1) * cap, dm)
+            if hi > lo:
+                k = hi - lo
+                uu[s * cap: s * cap + k] = du[lo:hi]
+                vv[s * cap: s * cap + k] = dv[lo:hi]
+                ww[s * cap: s * cap + k] = dw[lo:hi]
+                ee[s * cap: s * cap + k] = de[lo:hi]
+        return DistGraph(jnp.asarray(uu), jnp.asarray(vv), jnp.asarray(ww),
+                         jnp.asarray(ee)), cap
 
 
 # --------------------------------------------------------------------------

@@ -163,7 +163,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
+from repro import compat, obs
 from repro.comm import faults
 from repro.comm.exchange import (ExchangeStats, _hops, reply,
                                  routed_exchange, scatter_updates,
@@ -209,6 +209,7 @@ class VIndex(NamedTuple):
     key: jax.Array    # [cap] int32 — permuted keys (invalid slots = n)
 
 
+@obs.scope("sort")
 def _build_v_index(v: jax.Array, valid: jax.Array, n: int,
                    names: Tuple[str, ...],
                    perm: Optional[jax.Array] = None) -> VIndex:
@@ -390,6 +391,7 @@ def _bit_or_scatter(mask: jax.Array, idx: jax.Array, bits: jax.Array,
     return jnp.sum(acc[:L].astype(jnp.int32) << lanes, axis=1)
 
 
+@obs.scope("ghost_setup")
 def _ghost_setup(u, v, valid, live, lab, vperm, n: int, vps: int,
                  Gu: int, Gv: int, cap_fill_u: int, cap_fill_v: int,
                  cap_sub: int, axes: Tuple[str, ...], schedule: str,
@@ -473,6 +475,7 @@ def _ghost_setup(u, v, valid, live, lab, vperm, n: int, vps: int,
             o1 + o2 + ex.overflow, st)
 
 
+@obs.scope("push")
 def _ghost_push(gstate, parent: jax.Array, vps: int, capacity: int,
                 cap_col: int, axes: Tuple[str, ...], schedule: str,
                 stats: ExchangeStats, grid_push: bool = False):
@@ -619,119 +622,133 @@ def _sharded_preprocess(u, v, w, eid, valid, n: int, vps: int,
     Kruskal oracle.
 
     Returns (lab [vps], pre_mst [cap] bool, dead0 [cap] bool, overflow,
-    stats).  The owner scatter ships one (vid, root) pair per *changed
-    distinct vertex* (L = cap, down from the old L = n): an owner owns
-    ``vps`` vertices and a shard has at most cap distinct sources, so
-    the effective ``min(capacity, cap)`` stays overflow-free by
-    construction for the default ``label_capacity``.
+    stats, (rounds, live)): this shard's round count and its alive slots
+    summed over the rounds (int32 []).  The owner scatter ships one (vid,
+    root) pair per *changed distinct vertex* (L = cap, down from the old
+    L = n): an owner owns ``vps`` vertices and a shard has at most cap
+    distinct sources, so the effective ``min(capacity, cap)`` stays
+    overflow-free by construction for the default ``label_capacity``.
     """
     names = tuple(axes)
     cap = u.shape[0]
     big = jnp.int32(n)  # > every vertex id; doubles as "no vertex"
 
     # --- shard boundary structure (tiny [p] all_gathers, no [n] mask) --
-    cnt = jnp.sum(valid.astype(jnp.int32))
-    has_edges = cnt > 0
-    first = jnp.where(has_edges, u[0], -1)
-    last = jnp.where(has_edges, u[jnp.clip(cnt - 1, 0, cap - 1)], -2)
-    firsts = lax.all_gather(first, names, tiled=False).reshape(-1)
-    lasts = lax.all_gather(last, names, tiled=False).reshape(-1)
+    with obs.scope("sort"):
+        cnt = jnp.sum(valid.astype(jnp.int32))
+        has_edges = cnt > 0
+        first = jnp.where(has_edges, u[0], -1)
+        last = jnp.where(has_edges, u[jnp.clip(cnt - 1, 0, cap - 1)], -2)
+        firsts = lax.all_gather(first, names, tiled=False).reshape(-1)
+        lasts = lax.all_gather(last, names, tiled=False).reshape(-1)
     p = firsts.shape[0]
     k = max(p - 1, 1)
-    if p > 1:
-        shared = (lasts[:-1] == firsts[1:]) & (lasts[:-1] >= 0)
-        sh_ids = jnp.sort(jnp.where(shared, lasts[:-1].astype(jnp.int32),
-                                    big))
-    else:
-        sh_ids = compat.vary(jnp.full((k,), big), names)
+    with obs.scope("sort"):
+        if p > 1:
+            shared = (lasts[:-1] == firsts[1:]) & (lasts[:-1] >= 0)
+            sh_ids = jnp.sort(jnp.where(shared,
+                                        lasts[:-1].astype(jnp.int32), big))
+        else:
+            sh_ids = compat.vary(jnp.full((k,), big), names)
 
     def is_shared(x):
         j = jnp.clip(jnp.searchsorted(sh_ids, x), 0, k - 1)
         return sh_ids[j] == x
 
     # --- bucketed local vertex space: distinct sources by run rank -----
-    vu = jnp.where(valid, u, big)  # valid slots are a sorted prefix
-    head = jnp.concatenate([compat.vary(jnp.ones((1,), bool), names),
-                            vu[1:] != vu[:-1]])
-    du = jnp.cumsum(head.astype(jnp.int32)) - 1          # [cap] slot -> rank
-    uvals = compat.vary(jnp.full((cap,), big), names).at[du].set(vu)
-    dv = jnp.clip(jnp.searchsorted(uvals, v), 0, cap - 1)
-    v_found = (uvals[dv] == v) & valid
-    shared_rank = is_shared(uvals)
-    local_edge = valid & v_found & ~is_shared(u) & ~is_shared(v)
+    with obs.scope("sort"):
+        vu = jnp.where(valid, u, big)  # valid slots are a sorted prefix
+        head = jnp.concatenate([compat.vary(jnp.ones((1,), bool), names),
+                                vu[1:] != vu[:-1]])
+        du = jnp.cumsum(head.astype(jnp.int32)) - 1      # [cap] slot -> rank
+        uvals = compat.vary(jnp.full((cap,), big), names).at[du].set(vu)
+        dv = jnp.clip(jnp.searchsorted(uvals, v), 0, cap - 1)
+        v_found = (uvals[dv] == v) & valid
+        shared_rank = is_shared(uvals)
+        local_edge = valid & v_found & ~is_shared(u) & ~is_shared(v)
 
     iota = jnp.arange(cap, dtype=jnp.int32)
     sent = jnp.int32(cap)  # drop row of the [cap + 1] scatter arrays
     nloc = max(min(n, cap), 2)  # distinct local vertices <= min(n, cap)
+    max_rounds = _doubling_iters(nloc) + 1
 
     def round_(state):
-        lab, mst, _, r = state
-        ru = lab[du]
-        rvx = jnp.where(v_found, lab[dv], sent)
-        same = v_found & (lab[du] == lab[dv])
-        alive = valid & ~same
-        wk = jnp.where(alive, w, jnp.inf)
-        wmin = jnp.full((cap + 1,), jnp.inf, w.dtype
-                        ).at[ru].min(wk).at[rvx].min(wk)
-        # tie-break by the *global undirected* eid (not the local slot or
-        # rank) so the contracted edges are a subset of the unique
-        # (w, eid) MSF — the same total order every engine uses
-        at_min_u = jnp.isfinite(wk) & (wk == wmin[ru])
-        at_min_v = jnp.isfinite(wk) & (wk == wmin[rvx])
-        eminid = jnp.full((cap + 1,), ESENT, jnp.int32)
-        eminid = eminid.at[ru].min(jnp.where(at_min_u, eid, ESENT))
-        eminid = eminid.at[rvx].min(jnp.where(at_min_v, eid, ESENT))
-        cu = jnp.where(at_min_u & (eid == eminid[ru]), iota, sent)
-        cv = jnp.where(at_min_v & (eid == eminid[rvx]), iota, sent)
-        emin = jnp.full((cap + 1,), sent, jnp.int32
-                        ).at[ru].min(cu).at[rvx].min(cv)
-        has = emin[:cap] < sent
-        ce = jnp.clip(emin[:cap], 0, cap - 1)
-        # contract only if the component's global-min edge is local
-        eligible = has & local_edge[ce] & ~shared_rank
-        emin_m = jnp.where(eligible, emin[:cap], sent)
-        ce = jnp.clip(emin_m, 0, cap - 1)
-        cru = lab[du[ce]]
-        crv = lab[dv[ce]]
-        other = cru + crv - iota
-        parent = jnp.where(eligible, other, iota)
-        gp = parent[parent]
-        parent = jnp.where((gp == iota) & (iota < parent), iota, parent)
-        roots = lax.fori_loop(0, _doubling_iters(nloc),
-                              lambda _, p_: p_[p_], parent)
-        mst = mst.at[ce].max(eligible.astype(jnp.int32))
-        lab = roots[lab]
-        return lab, mst, jnp.any(eligible), r + 1
-
-    max_rounds = _doubling_iters(nloc) + 1
+        lab, mst, _, r, live = state
+        with obs.scope("label_gather"):
+            ru = lab[du]
+            rvx = jnp.where(v_found, lab[dv], sent)
+            same = v_found & (lab[du] == lab[dv])
+        with obs.scope("minedges"):
+            alive = valid & ~same
+            live = live + jnp.sum(alive.astype(jnp.int32))
+            wk = jnp.where(alive, w, jnp.inf)
+            wmin = jnp.full((cap + 1,), jnp.inf, w.dtype
+                            ).at[ru].min(wk).at[rvx].min(wk)
+            # tie-break by the *global undirected* eid (not the local slot
+            # or rank) so the contracted edges are a subset of the unique
+            # (w, eid) MSF — the same total order every engine uses
+            at_min_u = jnp.isfinite(wk) & (wk == wmin[ru])
+            at_min_v = jnp.isfinite(wk) & (wk == wmin[rvx])
+            eminid = jnp.full((cap + 1,), ESENT, jnp.int32)
+            eminid = eminid.at[ru].min(jnp.where(at_min_u, eid, ESENT))
+            eminid = eminid.at[rvx].min(jnp.where(at_min_v, eid, ESENT))
+            cu = jnp.where(at_min_u & (eid == eminid[ru]), iota, sent)
+            cv = jnp.where(at_min_v & (eid == eminid[rvx]), iota, sent)
+            emin = jnp.full((cap + 1,), sent, jnp.int32
+                            ).at[ru].min(cu).at[rvx].min(cv)
+        with obs.scope("contract"):
+            has = emin[:cap] < sent
+            ce = jnp.clip(emin[:cap], 0, cap - 1)
+            # contract only if the component's global-min edge is local
+            eligible = has & local_edge[ce] & ~shared_rank
+            emin_m = jnp.where(eligible, emin[:cap], sent)
+            ce = jnp.clip(emin_m, 0, cap - 1)
+            cru = lab[du[ce]]
+            crv = lab[dv[ce]]
+            other = cru + crv - iota
+            parent = jnp.where(eligible, other, iota)
+            gp = parent[parent]
+            parent = jnp.where((gp == iota) & (iota < parent), iota, parent)
+        with obs.scope("doubling"):
+            roots = lax.fori_loop(0, _doubling_iters(nloc),
+                                  lambda _, p_: p_[p_], parent)
+        with obs.scope("contract"):
+            mst = mst.at[ce].max(eligible.astype(jnp.int32))
+            lab = roots[lab]
+        return lab, mst, jnp.any(eligible), r + 1, live
 
     def cond(state):
         return state[2] & (state[3] < max_rounds)
 
     lab0 = compat.vary(iota, names)
     mst0 = compat.vary(jnp.zeros((cap,), jnp.int32), names)
-    lab, mst, _, _ = lax.while_loop(
+    live0 = compat.vary(jnp.int32(0), names)
+    lab, mst, _, rounds, live = lax.while_loop(
         cond, round_,
-        (lab0, mst0, compat.vary(jnp.array(True), names), jnp.int32(0)))
+        (lab0, mst0, compat.vary(jnp.array(True), names), jnp.int32(0),
+         live0))
 
     # --- one routed (vid, root) scatter to the owners ------------------
-    groot = uvals[lab]                 # [rank] -> global root vid
-    root_slot = groot[du]              # [cap] per-slot root of its source
-    changed = head & valid & (root_slot != u)
-    ex = routed_exchange((u, root_slot), u // vps, changed,
-                         min(capacity, cap), names, schedule, stats=stats,
-                         site="prep")
-    base = lax.axis_index(names) * vps
-    vid = base + jnp.arange(vps, dtype=jnp.int32)
-    rvid = ex.recv[0].reshape(-1)
-    rlab = ex.recv[1].reshape(-1)
-    ok = ex.recv_ok.reshape(-1)
-    off = jnp.where(ok, rvid - base, vps)  # vps = drop row
-    lab_out = jnp.concatenate([vid, jnp.full((1,), -1, jnp.int32)]
-                              ).at[off].set(rlab)[:vps]
-    same = v_found & (lab[du] == lab[dv])
-    dead0 = (u == v) | same  # locally-internal edges incl. self-loops
-    return lab_out, mst.astype(bool), dead0, ex.overflow, ex.stats
+    with obs.scope("exchange"):
+        groot = uvals[lab]                 # [rank] -> global root vid
+        root_slot = groot[du]              # [cap] per-slot root of its source
+        changed = head & valid & (root_slot != u)
+        ex = routed_exchange((u, root_slot), u // vps, changed,
+                             min(capacity, cap), names, schedule,
+                             stats=stats, site="prep")
+        base = lax.axis_index(names) * vps
+        vid = base + jnp.arange(vps, dtype=jnp.int32)
+        rvid = ex.recv[0].reshape(-1)
+        rlab = ex.recv[1].reshape(-1)
+        ok = ex.recv_ok.reshape(-1)
+        off = jnp.where(ok, rvid - base, vps)  # vps = drop row
+        lab_out = jnp.concatenate([vid, jnp.full((1,), -1, jnp.int32)]
+                                  ).at[off].set(rlab)[:vps]
+    with obs.scope("label_gather"):
+        same = v_found & (lab[du] == lab[dv])
+        dead0 = (u == v) | same  # locally-internal edges incl. self-loops
+    return (lab_out, mst.astype(bool), dead0, ex.overflow, ex.stats,
+            (rounds, live))
 
 
 def _owner_scatter_min(comp, wc, ec, oc, okc, base, vps: int,
@@ -775,6 +792,7 @@ def _owner_scatter_min(comp, wc, ec, oc, okc, base, vps: int,
     return has, other[:vps], is_win, off
 
 
+@obs.scope("minedges")
 def _sharded_minedges(ru, rv, wk, eid, alive, vps: int, capacity: int,
                       axes: Tuple[str, ...], schedule: str,
                       stats: ExchangeStats, use_pallas: bool = False):
@@ -823,6 +841,7 @@ def _sharded_minedges(ru, rv, wk, eid, alive, vps: int, capacity: int,
     return has, other, win, ex_u.overflow + ex_v.overflow, st
 
 
+@obs.scope("minedges")
 def _sharded_minedges_src(ru, rv, wk, eid, alive, runs, vps: int,
                           capacity: int, axes: Tuple[str, ...],
                           schedule: str, stats: ExchangeStats,
@@ -907,6 +926,7 @@ def _sharded_minedges_src(ru, rv, wk, eid, alive, runs, vps: int,
     return has, other, is_win, off, ex, loc_win, head_idx
 
 
+@obs.scope("doubling")
 def _sharded_contract(has, other, n: int, vps: int, capacity: int,
                       axes: Tuple[str, ...], schedule: str,
                       adaptive: bool, stats: ExchangeStats):
@@ -1010,50 +1030,55 @@ def _round_body(u, v, w, eid, live0, lab, mst, dead, runs_u, runs_v,
     """
     live = live0 & ~dead
     if ghost:
-        gu, gv = gstate[0], gstate[1]
-        head_u, _, run_id_u = runs_u
-        head_v, _, run_id_v = vidx.runs
-        au = compat.vary(jnp.zeros(live.shape, bool), names
-                         ).at[run_id_u].max(live)
-        # rank-keyed (never perm-keyed: see _vsorted_lookup) run-liveness
-        av = compat.vary(jnp.zeros(live.shape, bool), names
-                         ).at[vidx.rank].max(live)
-        hits = lax.psum(
-            jnp.sum((head_u & au[run_id_u]).astype(jnp.float32))
-            + jnp.sum((head_v & av[run_id_v]).astype(jnp.float32)), names)
-        st = stats._replace(hits=stats.hits + hits)
-        ru = gu[jnp.clip(run_id_u, 0, gu.shape[0] - 1)]
-        rv = gv[jnp.clip(vidx.rank, 0, gv.shape[0] - 1)]
-        looked = live
-        o1 = o2 = jnp.int32(0)
+        with obs.scope("label_gather"):
+            gu, gv = gstate[0], gstate[1]
+            head_u, _, run_id_u = runs_u
+            head_v, _, run_id_v = vidx.runs
+            au = compat.vary(jnp.zeros(live.shape, bool), names
+                             ).at[run_id_u].max(live)
+            # rank-keyed (never perm-keyed: see _vsorted_lookup)
+            # run-liveness
+            av = compat.vary(jnp.zeros(live.shape, bool), names
+                             ).at[vidx.rank].max(live)
+            hits = lax.psum(
+                jnp.sum((head_u & au[run_id_u]).astype(jnp.float32))
+                + jnp.sum((head_v & av[run_id_v]).astype(jnp.float32)),
+                names)
+            st = stats._replace(hits=stats.hits + hits)
+            ru = gu[jnp.clip(run_id_u, 0, gu.shape[0] - 1)]
+            rv = gv[jnp.clip(vidx.rank, 0, gv.shape[0] - 1)]
+            looked = live
+            o1 = o2 = jnp.int32(0)
     else:
         # dispatch here, not inside _coalesced_lookup: exactly one of
         # the two paths runs per endpoint, each booking its own slots
         # once (runs_u may exist for src_only even when coalesce is off)
-        if coalesce and runs_u is not None:
-            ru, ok_u, o1, st = _coalesced_lookup(
-                lab, u, runs_u, live, vps, cap_lookup, names, schedule,
-                stats)
-        else:
-            ru, ok_u, o1, st = _sharded_lookup(
-                lab, u, live, vps, cap_lookup, names, schedule,
-                stats=stats, count_misses=True)
-        if coalesce and vidx is not None:
-            rv, ok_v, o2, st = _vsorted_lookup(
-                lab, vidx, live, vps, cap_lookup, names, schedule, st)
-        elif coalesce and runs_v is not None:
-            rv, ok_v, o2, st = _coalesced_lookup(
-                lab, v, runs_v, live, vps, cap_lookup, names, schedule,
-                st)
-        else:
-            rv, ok_v, o2, st = _sharded_lookup(
-                lab, v, live, vps, cap_lookup, names, schedule,
-                stats=st, count_misses=True)
-        looked = ok_u & ok_v
-    # dead-edge retirement: same component now => same forever
-    dead = dead | (looked & (ru == rv))
-    alive = looked & (ru != rv) & live
-    wk = jnp.where(alive, w, jnp.inf)
+        with obs.scope("lookup"):
+            if coalesce and runs_u is not None:
+                ru, ok_u, o1, st = _coalesced_lookup(
+                    lab, u, runs_u, live, vps, cap_lookup, names, schedule,
+                    stats)
+            else:
+                ru, ok_u, o1, st = _sharded_lookup(
+                    lab, u, live, vps, cap_lookup, names, schedule,
+                    stats=stats, count_misses=True)
+            if coalesce and vidx is not None:
+                rv, ok_v, o2, st = _vsorted_lookup(
+                    lab, vidx, live, vps, cap_lookup, names, schedule, st)
+            elif coalesce and runs_v is not None:
+                rv, ok_v, o2, st = _coalesced_lookup(
+                    lab, v, runs_v, live, vps, cap_lookup, names, schedule,
+                    st)
+            else:
+                rv, ok_v, o2, st = _sharded_lookup(
+                    lab, v, live, vps, cap_lookup, names, schedule,
+                    stats=st, count_misses=True)
+            looked = ok_u & ok_v
+    with obs.scope("minedges"):
+        # dead-edge retirement: same component now => same forever
+        dead = dead | (looked & (ru == rv))
+        alive = looked & (ru != rv) & live
+        wk = jnp.where(alive, w, jnp.inf)
     if src_only:
         has, other, is_win, off, ex, loc_win, head_idx = \
             _sharded_minedges_src(ru, rv, wk, eid, alive, runs_u, vps,
@@ -1062,12 +1087,13 @@ def _round_body(u, v, w, eid, live0, lab, mst, dead, runs_u, runs_v,
         parent, keep, o4, st = _sharded_contract(
             has, other, n, vps, cap_contract, names, schedule, adaptive,
             ex.stats)
-        keep_ext = jnp.concatenate([keep, jnp.zeros((1,), bool)])
-        confirm = (is_win & keep_ext[off]).reshape(ex.recv_ok.shape)
-        win, st = reply(ex, confirm, names, schedule, stats=st)
-        # per-run confirmation fans back onto the run's argmin slot;
-        # owner-side dedup => exactly one directed slot per MSF edge
-        mst = mst | (loc_win & (win & ex.sent_ok)[head_idx])
+        with obs.scope("contract"):
+            keep_ext = jnp.concatenate([keep, jnp.zeros((1,), bool)])
+            confirm = (is_win & keep_ext[off]).reshape(ex.recv_ok.shape)
+            win, st = reply(ex, confirm, names, schedule, stats=st)
+            # per-run confirmation fans back onto the run's argmin slot;
+            # owner-side dedup => exactly one directed slot per MSF edge
+            mst = mst | (loc_win & (win & ex.sent_ok)[head_idx])
         o3 = ex.overflow
     else:
         has, other, win, o3, st = _sharded_minedges(
@@ -1075,24 +1101,27 @@ def _round_body(u, v, w, eid, live0, lab, mst, dead, runs_u, runs_v,
             pallas_minedges)
         # both directed copies are confirmed; mark only the canonical
         # one so the global mask is exact-once
-        mst = mst | (win & (u < v))
+        with obs.scope("contract"):
+            mst = mst | (win & (u < v))
         parent, _, o4, st = _sharded_contract(
             has, other, n, vps, cap_contract, names, schedule, adaptive,
             st)
-    if relabel_skip:
-        lab, settled, o5, st = _relabel_lookup(
-            parent, has, lab, settled, vps, cap_label, names, schedule,
-            st)
-    else:
-        lab, _, o5, st = _sharded_lookup(
-            parent, lab, compat.vary(jnp.ones((vps,), bool), names), vps,
-            cap_label, names, schedule, stats=st, site="relabel")
+    with obs.scope("contract"):
+        if relabel_skip:
+            lab, settled, o5, st = _relabel_lookup(
+                parent, has, lab, settled, vps, cap_label, names, schedule,
+                st)
+        else:
+            lab, _, o5, st = _sharded_lookup(
+                parent, lab, compat.vary(jnp.ones((vps,), bool), names),
+                vps, cap_label, names, schedule, stats=st, site="relabel")
     o6 = jnp.int32(0)
     if ghost:
         gstate, o6, st = _ghost_push(gstate, parent, vps, cap_push,
                                      cap_push_col, names, schedule, st,
                                      grid_push)
-    go = lax.psum(jnp.sum(has.astype(jnp.int32)), names) > 0
+    with obs.scope("contract"):
+        go = lax.psum(jnp.sum(has.astype(jnp.int32)), names) > 0
     return (lab, mst, dead, gstate, settled, go,
             o1 + o2 + o3 + o4 + o5 + o6, st)
 
@@ -1183,7 +1212,7 @@ def _sharded_shard_fn(u, v, w, eid, n: int, vps: int,
         else max_rounds
 
     if local_preprocessing:
-        lab, pre_mst, dead, ovf, stats = _sharded_preprocess(
+        lab, pre_mst, dead, ovf, stats, _ = _sharded_preprocess(
             u, v, w, eid, valid, n, vps, cap_label, names, schedule, stats)
         overflow += ovf
     else:
@@ -1286,11 +1315,18 @@ def _stat_leaves(st: ExchangeStats):
 def _sharded_prep_shard_fn(u, v, w, eid, n: int, vps: int,
                            axes: Tuple[str, ...], cap_label: int,
                            schedule: str):
+    """The preprocessing program.  Returns (lab, pre_mst, dead0, overflow,
+    counters, *stat leaves): ``counters`` are ``obs.record``'s, summed
+    over the shards (rounds: the most any shard ran)."""
+    names = tuple(axes)
     valid = jnp.isfinite(w)
-    lab, pre_mst, dead0, ovf, st = _sharded_preprocess(
-        u, v, w, eid, valid, n, vps, cap_label, tuple(axes), schedule,
+    lab, pre_mst, dead0, ovf, st, (rounds, live) = _sharded_preprocess(
+        u, v, w, eid, valid, n, vps, cap_label, names, schedule,
         ExchangeStats.zeros())
-    return (lab, pre_mst, dead0, ovf) + _stat_leaves(st)
+    counters = {"rounds": lax.pmax(rounds, names),
+                "live_slots": lax.psum(live, names),
+                "slot_rounds": lax.psum(rounds * u.shape[0], names)}
+    return (lab, pre_mst, dead0, ovf, counters) + _stat_leaves(st)
 
 
 @functools.lru_cache(maxsize=64)
@@ -1302,7 +1338,7 @@ def _build_sharded_prep_fn(n: int, vps: int, mesh: jax.sharding.Mesh,
     spec = P(axes)
     return jax.jit(compat.shard_map(
         fn, mesh=mesh, in_specs=(spec, spec, spec, spec),
-        out_specs=(spec, spec, spec) + (P(),) * (1 + _STAT_FIELDS)))
+        out_specs=(spec, spec, spec) + (P(),) * (2 + _STAT_FIELDS)))
 
 
 def _ghost_setup_shard_fn(u, v, w, dead, vperm, lab, n: int, vps: int,
@@ -1348,10 +1384,12 @@ def _sharded_round_shard_fn(u, v, w, eid, vperm, lab, mst, dead, gu, gv,
     valid = jnp.isfinite(w)
     live0 = valid & (w > compat.vary(lo, names)) \
         & (w <= compat.vary(hi, names))
-    runs_u = run_metadata(u) if (coalesce or src_only or ghost) else None
-    vidx = _build_v_index(v, valid, n, names, perm=vperm) \
-        if ((coalesce and vsorted) or ghost) else None
-    runs_v = run_metadata(v) if (coalesce and not vsorted) else None
+    with obs.scope("sort"):
+        runs_u = run_metadata(u) if (coalesce or src_only or ghost) \
+            else None
+        vidx = _build_v_index(v, valid, n, names, perm=vperm) \
+            if ((coalesce and vsorted) or ghost) else None
+        runs_v = run_metadata(v) if (coalesce and not vsorted) else None
     gstate = (gu, gv, rs_row, rs_col) if ghost else None
     lab, mst, dead, gstate, settled, go, ovf, st = _round_body(
         u, v, w, eid, live0, lab, mst, dead, runs_u, runs_v, vidx,
@@ -1786,10 +1824,11 @@ def _shrinking_capacity_msf(graph: DistGraph, n: int,
     cap = graph.cap_total // p
     mr = (math.ceil(math.log2(max(n, 2))) + 1) if max_rounds is None \
         else max_rounds
-    u_h = np.asarray(graph.u)
-    v_h = np.asarray(graph.v)
-    w_h = np.asarray(graph.w)
-    valid_h = np.isfinite(w_h)
+    with obs.span("driver.readback"):
+        u_h = np.asarray(graph.u)
+        v_h = np.asarray(graph.v)
+        w_h = np.asarray(graph.w)
+        valid_h = np.isfinite(w_h)
     hops = _hops(axes, schedule)
 
     if plan_out is not None and (resume_from is not None or ckpt_every):
@@ -1799,6 +1838,7 @@ def _shrinking_capacity_msf(graph: DistGraph, n: int,
 
     overflow = 0
     acc = np.zeros(_STAT_FIELDS, np.float64)
+    prep_counters = {}
     if resume_from is not None:
         # re-entry (ISSUE 9): the certified snapshot replaces the
         # preprocessing product wholesale — labels, masks and position
@@ -1816,49 +1856,53 @@ def _shrinking_capacity_msf(graph: DistGraph, n: int,
         acc += ck.stats_acc
         ghost = ghost and ck.ghost_on
     elif local_preprocessing:
-        prep = _build_sharded_prep_fn(n, vps, mesh, tuple(axes), cl,
-                                      schedule)
-        lab, pre_mst, dead, ovf, *st = prep(graph.u, graph.v, graph.w,
-                                            graph.eid)
-        overflow += int(ovf)
-        acc += [float(x) for x in st]
+        with obs.span("driver.prep"):
+            prep = _build_sharded_prep_fn(n, vps, mesh, tuple(axes), cl,
+                                          schedule)
+            lab, pre_mst, dead, ovf, prep_counters, *st = prep(
+                graph.u, graph.v, graph.w, graph.eid)
+            overflow += int(ovf)
+            acc += [float(x) for x in st]
         mst = jnp.zeros((p * cap,), bool)
     else:
         lab = jnp.arange(p * vps, dtype=jnp.int32)
         pre_mst = jnp.zeros((p * cap,), bool)
         dead = jnp.asarray(u_h == v_h)
         mst = jnp.zeros((p * cap,), bool)
-    dead_h = np.asarray(dead)
 
     # static host structures: source-run heads (src-only aggregation +
     # u-side fill bound) and the v-sorted secondary index
-    shard_of = np.repeat(np.arange(p), cap)
-    heads, rid = _host_run_heads(u_h, p)
-    vperm_h, skey = _host_v_perm(v_h, valid_h, n, p)
-    vperm = jnp.asarray(vperm_h.astype(np.int32))
+    with obs.span("driver.index"):
+        dead_h = np.asarray(dead)
+        shard_of = np.repeat(np.arange(p), cap)
+        heads, rid = _host_run_heads(u_h, p)
+        vperm_h, skey = _host_v_perm(v_h, valid_h, n, p)
+        vperm = jnp.asarray(vperm_h.astype(np.int32))
 
     ghost_on = ghost
     ghosts = None
     if ghost_on:
-        live_setup = valid_h & ~dead_h
-        Gu = _host_run_count_max(heads, p)
-        Gv = _host_run_count_max(_host_run_heads(skey, p)[0], p)
-        ghosts = _host_ghost_lists(u_h, v_h, live_setup, p)
-        bu, bv = _ghost_fill_bounds(u_h, live_setup, vperm_h, skey, n,
-                                    p, vps)
-        bs = _subscribe_capacity_bound(np.asarray(lab), ghosts, p, vps)
-        qfu = quantize_capacity(bu, lk_full)
-        qfv = quantize_capacity(bv, lk_full)
-        qsub = quantize_capacity(bs, vps)
-        if plan_out is not None:
-            plan_out["ghost"] = GhostPlan(Gu, Gv, qfu, qfv, qsub)
-        setup = _build_ghost_setup_fn(
-            n, vps, mesh, tuple(axes), Gu, Gv, qfu, qfv, qsub, schedule,
-            grid_push)
-        gu, gv, rsubs_dev, rsubc_dev, ovf, *st = setup(
-            graph.u, graph.v, graph.w, dead, vperm, lab)
-        overflow += int(ovf)
-        acc += [float(x) for x in st]
+        with obs.span("driver.ghost_bounds"):
+            live_setup = valid_h & ~dead_h
+            Gu = _host_run_count_max(heads, p)
+            Gv = _host_run_count_max(_host_run_heads(skey, p)[0], p)
+            ghosts = _host_ghost_lists(u_h, v_h, live_setup, p)
+            bu, bv = _ghost_fill_bounds(u_h, live_setup, vperm_h, skey, n,
+                                        p, vps)
+            bs = _subscribe_capacity_bound(np.asarray(lab), ghosts, p, vps)
+            qfu = quantize_capacity(bu, lk_full)
+            qfv = quantize_capacity(bv, lk_full)
+            qsub = quantize_capacity(bs, vps)
+            if plan_out is not None:
+                plan_out["ghost"] = GhostPlan(Gu, Gv, qfu, qfv, qsub)
+        with obs.span("driver.ghost_setup"):
+            setup = _build_ghost_setup_fn(
+                n, vps, mesh, tuple(axes), Gu, Gv, qfu, qfv, qsub, schedule,
+                grid_push)
+            gu, gv, rsubs_dev, rsubc_dev, ovf, *st = setup(
+                graph.u, graph.v, graph.w, dead, vperm, lab)
+            overflow += int(ovf)
+            acc += [float(x) for x in st]
     else:
         gu = gv = jnp.zeros((p,), jnp.int32)  # [1] per shard placeholder
         rsubs_dev = jnp.zeros((p,), jnp.int32)
@@ -1886,6 +1930,7 @@ def _shrinking_capacity_msf(graph: DistGraph, n: int,
         plan_out["rounds"] = []
 
     rounds = 0
+    step_live = []  # alive slots of each dispatched round step
     start_lvl = start_r = 0
     settled_resume = None
     if resume_from is not None:
@@ -1896,16 +1941,17 @@ def _shrinking_capacity_msf(graph: DistGraph, n: int,
     for lvl, (lo, hi) in enumerate(windows):
         if lvl < start_lvl:
             continue
-        active_h = valid_h & (w_h > lo) & (w_h <= hi)
-        # settled is per level: a new weight window revives edges
-        if lvl == start_lvl and settled_resume is not None:
-            settled_dev = jnp.asarray(settled_resume)
-            settled_h = settled_resume.copy()
-            r = start_r
-        else:
-            settled_dev = jnp.zeros((p * vps,), bool)
-            settled_h = np.zeros(p * vps, bool)
-            r = 0
+        with obs.span("driver.bounds"):
+            active_h = valid_h & (w_h > lo) & (w_h <= hi)
+            # settled is per level: a new weight window revives edges
+            if lvl == start_lvl and settled_resume is not None:
+                settled_dev = jnp.asarray(settled_resume)
+                settled_h = settled_resume.copy()
+                r = start_r
+            else:
+                settled_dev = jnp.zeros((p * vps,), bool)
+                settled_h = np.zeros(p * vps, bool)
+                r = 0
         while r < mr:
             if overflow:
                 # a user-undersized capacity already dropped items: the
@@ -1913,99 +1959,101 @@ def _shrinking_capacity_msf(graph: DistGraph, n: int,
                 # larger), and garbage labels would poison the host
                 # bounds — stop burning rounds and report
                 break
-            live_h = active_h & ~dead_h
-            lab_h = np.asarray(lab)
-            ru_h = lab_h[u_h]
-            rv_h = lab_h[v_h]
-            alive_h = live_h & (ru_h != rv_h)
-            bound_e = _minedges_capacity_bound(ru_h, rv_h, alive_h,
-                                               shard_of, heads, rid, p,
-                                               vps, src_only)
-            ce_r = quantize_capacity(bound_e, ce_full)
-            choosing = np.zeros(p * vps, bool)
-            choosing[np.unique(ru_h[alive_h])] = True
-            ghost_round = ghost_on
-            cp_r = 1
-            cpc_r = 0
-            pb_flat = 0
-            if ghost_round:
-                pb_flat = _push_capacity_bound(lab_h, ghosts, choosing,
-                                               p, vps)
-                if grid_push:
-                    pb, pbc = _push_capacity_bound_grid(
-                        lab_h, ghosts, choosing, p, R, C, vps)
-                    # the deputy hop's ceiling is every owned root once
-                    # per source column; C*vps always holds a rung >= pbc
-                    cpc_r = quantize_capacity(pbc, C * vps)
+            with obs.span("driver.bounds"):
+                live_h = active_h & ~dead_h
+                lab_h = np.asarray(lab)
+                ru_h = lab_h[u_h]
+                rv_h = lab_h[v_h]
+                alive_h = live_h & (ru_h != rv_h)
+                n_alive = int(np.count_nonzero(alive_h))
+                bound_e = _minedges_capacity_bound(ru_h, rv_h, alive_h,
+                                                   shard_of, heads, rid, p,
+                                                   vps, src_only)
+                ce_r = quantize_capacity(bound_e, ce_full)
+                choosing = np.zeros(p * vps, bool)
+                choosing[np.unique(ru_h[alive_h])] = True
+                ghost_round = ghost_on
+                cp_r = 1
+                cpc_r = 0
+                pb_flat = 0
+                if ghost_round:
+                    pb_flat = _push_capacity_bound(lab_h, ghosts, choosing,
+                                                   p, vps)
+                    if grid_push:
+                        pb, pbc = _push_capacity_bound_grid(
+                            lab_h, ghosts, choosing, p, R, C, vps)
+                        # the deputy hop's ceiling is every owned root once
+                        # per source column; C*vps always holds a rung >= pbc
+                        cpc_r = quantize_capacity(pbc, C * vps)
+                    else:
+                        pb = pb_flat
+                    cp_r = quantize_capacity(pb, vps) \
+                        if push_capacity is None else int(push_capacity)
+                    if cp_r < pb:
+                        # graceful exact fallback: a user-pinned push
+                        # capacity that cannot hold the worst-case dirty set
+                        # would leave stale ghost entries; abandon the cache
+                        # and finish with exact coalesced lookups instead of
+                        # risking a wrong (if reported) answer
+                        ghost_on = ghost_round = False
+                        cp_r = 1
+                        cpc_r = 0
+                coalesce_eff = coalesce or (ghost and not ghost_round)
+                # after a ghost fallback the v-sorted machinery is already
+                # built, so the fallback lookups always use it
+                vsorted_eff = vsorted or (ghost and not ghost_round)
+                if ghost_round:
+                    lk_r = 1  # no endpoint lookups are traced
+                elif coalesce_eff:
+                    lk_r = quantize_capacity(
+                        default_lookup_capacity(graph, p, n, alive=live_h,
+                                                vsorted=vsorted_eff,
+                                                vindex=(vperm_h, skey)),
+                        lk_full)
                 else:
-                    pb = pb_flat
-                cp_r = quantize_capacity(pb, vps) \
-                    if push_capacity is None else int(push_capacity)
-                if cp_r < pb:
-                    # graceful exact fallback: a user-pinned push
-                    # capacity that cannot hold the worst-case dirty set
-                    # would leave stale ghost entries; abandon the cache
-                    # and finish with exact coalesced lookups instead of
-                    # risking a wrong (if reported) answer
-                    ghost_on = ghost_round = False
-                    cp_r = 1
-                    cpc_r = 0
-            coalesce_eff = coalesce or (ghost and not ghost_round)
-            # after a ghost fallback the v-sorted machinery is already
-            # built, so the fallback lookups always use it
-            vsorted_eff = vsorted or (ghost and not ghost_round)
-            if ghost_round:
-                lk_r = 1  # no endpoint lookups are traced
-            elif coalesce_eff:
-                lk_r = quantize_capacity(
-                    default_lookup_capacity(graph, p, n, alive=live_h,
-                                            vsorted=vsorted_eff,
-                                            vindex=(vperm_h, skey)),
-                    lk_full)
-            else:
-                lk_r = quantize_capacity(
-                    _endpoint_lookup_bound(u_h, v_h, live_h, shard_of,
-                                           p, vps), lk_full)
-            con_r = quantize_capacity(
-                _contract_capacity_bound(ru_h, rv_h, alive_h, vps), cl)
-            if relabel_skip:
-                rl_r = quantize_capacity(
-                    _relabel_capacity_bound(lab_h, settled_h, p, vps), cl)
-            else:
-                rl_r = cl
-            if plan_out is not None:
-                plan_out["rounds"].append(RoundSpec(
-                    level=lvl, cap_edge=ce_r, cap_lookup=lk_r,
-                    cap_contract=con_r, cap_relabel=rl_r, cap_push=cp_r,
-                    ghost=bool(ghost_round), sentinel=(bound_e == 0),
-                    cap_push_col=cpc_r))
+                    lk_r = quantize_capacity(
+                        _endpoint_lookup_bound(u_h, v_h, live_h, shard_of,
+                                               p, vps), lk_full)
+                con_r = quantize_capacity(
+                    _contract_capacity_bound(ru_h, rv_h, alive_h, vps), cl)
+                if relabel_skip:
+                    rl_r = quantize_capacity(
+                        _relabel_capacity_bound(lab_h, settled_h, p, vps), cl)
+                else:
+                    rl_r = cl
+                if plan_out is not None:
+                    plan_out["rounds"].append(RoundSpec(
+                        level=lvl, cap_edge=ce_r, cap_lookup=lk_r,
+                        cap_contract=con_r, cap_relabel=rl_r, cap_push=cp_r,
+                        ghost=bool(ghost_round), sentinel=(bound_e == 0),
+                        cap_push_col=cpc_r))
             if bound_e == 0:
                 break  # no candidate exists: go would come back False
             # publish the 1-based round for abort-kind fault specs
             # (no-op unless an abort spec is active)
             faults.set_round(rounds + 1)
-            step = _build_sharded_round_fn(
-                n, vps, mesh, tuple(axes), ce_r, rl_r, lk_r, con_r,
-                cp_r, cpc_r, schedule, coalesce_eff, src_only, adaptive,
-                ghost_round, relabel_skip, vsorted_eff, pallas_minedges,
-                grid_push and ghost_round)
-            (lab, mst, dead, gu, gv, rsubs_dev, rsubc_dev, settled_dev,
-             go, ovf, *st) = step(
-                graph.u, graph.v, graph.w, graph.eid, vperm, lab, mst,
-                dead, gu, gv, rsubs_dev, rsubc_dev, settled_dev,
-                jnp.float32(lo), jnp.float32(hi))
-            overflow += int(ovf)
-            acc += [float(x) for x in st]
-            dead_h = np.asarray(dead)
-            if relabel_skip:
-                # mirror of the device's monotone settled update: a
-                # requesting vertex settles iff its (pre-contraction)
-                # component chose nothing this round
-                settled_h = settled_h | ~choosing[lab_h]
-            rounds += 1
-            r += 1
-            if round_trace is not None:
-                round_trace.append({
+            with obs.span("driver.step") as span:
+                step = _build_sharded_round_fn(
+                    n, vps, mesh, tuple(axes), ce_r, rl_r, lk_r, con_r,
+                    cp_r, cpc_r, schedule, coalesce_eff, src_only, adaptive,
+                    ghost_round, relabel_skip, vsorted_eff, pallas_minedges,
+                    grid_push and ghost_round)
+                (lab, mst, dead, gu, gv, rsubs_dev, rsubc_dev, settled_dev,
+                 go, ovf, *st) = step(
+                    graph.u, graph.v, graph.w, graph.eid, vperm, lab, mst,
+                    dead, gu, gv, rsubs_dev, rsubc_dev, settled_dev,
+                    jnp.float32(lo), jnp.float32(hi))
+                overflow += int(ovf)
+                acc += [float(x) for x in st]
+                dead_h = np.asarray(dead)
+                if relabel_skip:
+                    # mirror of the device's monotone settled update: a
+                    # requesting vertex settles iff its (pre-contraction)
+                    # component chose nothing this round
+                    settled_h = settled_h | ~choosing[lab_h]
+                rounds += 1
+                r += 1
+                rec = {
                     "round": rounds, "level": lvl,
                     "cap_edge": ce_r, "cap_lookup": lk_r,
                     "cap_contract": con_r, "cap_relabel": rl_r,
@@ -2024,7 +2072,13 @@ def _shrinking_capacity_msf(graph: DistGraph, n: int,
                     "lookup_items": float(st[5]),
                     "pushed_items": float(st[6]),
                     "injected_items": float(st[7]),
-                })
+                }
+                # one record per round: the step span's args and the
+                # caller's round_trace entry
+                span.set_metadata(**rec)
+            step_live.append(n_alive)
+            if round_trace is not None:
+                round_trace.append(rec)
             if (ckpt_out is not None and ckpt_every
                     and rounds % ckpt_every == 0 and not overflow):
                 # cadence boundary: certify, then snapshot the re-entry
@@ -2042,15 +2096,21 @@ def _shrinking_capacity_msf(graph: DistGraph, n: int,
             if not bool(go):
                 break
 
-    mask = np.asarray(mst) | np.asarray(pre_mst)
-    weight = np.float32(np.sum(w_h[mask], dtype=np.float64))
-    count = np.int32(int(mask.sum()))
-    comm = CommStats(np.int32(acc[0]), np.float32(acc[1]),
-                     np.float32(acc[2]), np.int32(rounds),
-                     np.float32(acc[4]), np.float32(acc[5]),
-                     np.float32(acc[6]), np.float32(acc[7]))
-    return (jnp.asarray(mask), weight, count, lab, np.int32(overflow),
-            comm)
+    with obs.span("driver.finish"):
+        mask = np.asarray(mst) | np.asarray(pre_mst)
+        weight = np.float32(np.sum(w_h[mask], dtype=np.float64))
+        count = np.int32(int(mask.sum()))
+        comm = CommStats(np.int32(acc[0]), np.float32(acc[1]),
+                         np.float32(acc[2]), np.int32(rounds),
+                         np.float32(acc[4]), np.float32(acc[5]),
+                         np.float32(acc[6]), np.float32(acc[7]))
+        out = (jnp.asarray(mask), weight, count, lab, np.int32(overflow),
+               comm)
+    obs.record(rounds=[prep_counters.get("rounds", 0), len(step_live)],
+               live_slots=[prep_counters.get("live_slots", 0), step_live],
+               slot_rounds=[prep_counters.get("slot_rounds", 0),
+                            len(step_live) * p * cap])
+    return out
 
 
 # --------------------------------------------------------------------------
@@ -2095,7 +2155,7 @@ def _planned_shard_fn(u, v, w, eid, n: int, vps: int,
     stats = ExchangeStats.zeros()
 
     if plan.local_preprocessing:
-        lab, pre_mst, dead, ovf, stats = _sharded_preprocess(
+        lab, pre_mst, dead, ovf, stats, _ = _sharded_preprocess(
             u, v, w, eid, valid, n, vps, plan.cap_prep, names,
             plan.schedule, stats)
         overflow += ovf
@@ -2241,7 +2301,7 @@ def _planned_segment_shard_fn(u, v, w, eid, lab0=None, mst0=None,
         lab = base + jnp.arange(vps, dtype=jnp.int32)
         mst = compat.vary(jnp.zeros(u.shape, bool), names)
         if plan.local_preprocessing:
-            lab, pre_mst, dead, ovf, stats = _sharded_preprocess(
+            lab, pre_mst, dead, ovf, stats, _ = _sharded_preprocess(
                 u, v, w, eid, valid, n, vps, plan.cap_prep, names,
                 plan.schedule, stats)
             overflow += ovf
@@ -2660,6 +2720,7 @@ def plan_sharded_msf(graph: DistGraph, n: int, mesh: jax.sharding.Mesh,
     ``round_trace`` passes through to the driver, so one call yields
     both the plan and the measured per-round comm table.
     """
+    obs.begin()
     axes = tuple(axis_names or mesh.axis_names)
     p = 1
     for a in axes:
@@ -2675,9 +2736,10 @@ def plan_sharded_msf(graph: DistGraph, n: int, mesh: jax.sharding.Mesh,
     ce = int(cap if edge_capacity is None else edge_capacity)
     cl = int(vps if label_capacity is None else label_capacity)
     if lookup_capacity is None:
-        lk = default_lookup_capacity(
-            graph, p, n, vsorted=vsorted_index or ghost_cache) \
-            if (coalesce or ghost_cache) else ce
+        with obs.span("driver.lookup_bound"):
+            lk = default_lookup_capacity(
+                graph, p, n, vsorted=vsorted_index or ghost_cache) \
+                if (coalesce or ghost_cache) else ce
     else:
         lk = int(lookup_capacity)
     rec: dict = {}
@@ -3056,6 +3118,7 @@ def distributed_sharded_msf(graph: DistGraph, n: int,
     additionally ``ghost_cache=False, vsorted_index=False`` on top of
     the defaults reproduces the PR 3 optimized engine.
     """
+    obs.begin()
     axes = tuple(axis_names or mesh.axis_names)
     p = 1
     for a in axes:
@@ -3115,9 +3178,10 @@ def distributed_sharded_msf(graph: DistGraph, n: int,
             "engine; pass plan=plan_sharded_msf(...) to AOT-lower the "
             "shrinking schedule", stacklevel=2)
     if lookup_capacity is None:
-        lk = default_lookup_capacity(
-            graph, p, n, vsorted=vsorted_index or ghost_cache) \
-            if ((coalesce or ghost_cache) and concrete) else ce
+        with obs.span("driver.lookup_bound"):
+            lk = default_lookup_capacity(
+                graph, p, n, vsorted=vsorted_index or ghost_cache) \
+                if ((coalesce or ghost_cache) and concrete) else ce
     else:
         lk = int(lookup_capacity)
     if shrink_capacities and concrete:

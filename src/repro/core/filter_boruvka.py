@@ -28,8 +28,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.boruvka import boruvka_round
+from repro import obs
 from repro.core import oracle
+from repro.core.boruvka import round_counters, run_rounds
 
 
 # --------------------------------------------------------------------------
@@ -41,41 +42,46 @@ def _bucket_rounds(bucket: int, n: int) -> int:
 
 
 @partial(jax.jit, static_argnames=("n", "num_buckets"))
-def filter_boruvka_msf(u: jax.Array, v: jax.Array, w: jax.Array, n: int,
-                       num_buckets: int = 8
-                       ) -> Tuple[jax.Array, jax.Array]:
-    """Jittable Filter-Borůvka. Returns (mst_mask[m], labels[n])."""
+def filter_boruvka_msf_counted(u: jax.Array, v: jax.Array, w: jax.Array,
+                               n: int, num_buckets: int = 8
+                               ) -> Tuple[jax.Array, jax.Array, dict]:
+    """``filter_boruvka_msf`` and its round counters, summed over the
+    buckets (``core/boruvka.py: round_counters``)."""
     m = u.shape[0]
     num_buckets = max(1, min(num_buckets, m))
     bucket = -(-m // num_buckets)
     pad = bucket * num_buckets - m
-    order = jnp.argsort(w, stable=True)  # ties broken by index: (w, idx)
-    us = jnp.concatenate([u[order], jnp.zeros((pad,), u.dtype)])
-    vs = jnp.concatenate([v[order], jnp.zeros((pad,), v.dtype)])
-    ws = jnp.concatenate([w[order], jnp.full((pad,), jnp.inf, w.dtype)])
+    with obs.scope("sort"):
+        order = jnp.argsort(w, stable=True)  # ties broken by index: (w, idx)
+        us = jnp.concatenate([u[order], jnp.zeros((pad,), u.dtype)])
+        vs = jnp.concatenate([v[order], jnp.zeros((pad,), v.dtype)])
+        ws = jnp.concatenate([w[order], jnp.full((pad,), jnp.inf, w.dtype)])
 
     labels = jnp.arange(n, dtype=jnp.int32)
     mask_sorted = jnp.zeros((num_buckets * bucket,), bool)
+    counters = []
 
     for b in range(num_buckets):  # static schedule of quantile buckets
         sl = slice(b * bucket, (b + 1) * bucket)
-        ub, vb, wb = us[sl], vs[sl], ws[sl]
-        mb = jnp.zeros((bucket,), bool)
+        final = run_rounds(us[sl], vs[sl], ws[sl], labels,
+                           jnp.zeros((bucket,), bool), n,
+                           _bucket_rounds(bucket, n))
+        labels = final.labels
+        counters.append(round_counters(final, bucket))
+        with obs.scope("sort"):
+            mask_sorted = mask_sorted.at[sl].set(final.mst)
 
-        def cond(s):
-            labels_, mb_, changed, r = s
-            return changed & (r < _bucket_rounds(bucket, n))
+    with obs.scope("sort"):
+        mask = jnp.zeros((m,), bool).at[order].set(mask_sorted[:m])
+    return mask, labels, {k: [c[k] for c in counters] for k in counters[0]}
 
-        def body(s):
-            labels_, mb_, changed, r = s
-            labels_, mb_, changed = boruvka_round(ub, vb, wb, labels_, mb_, n)
-            return labels_, mb_, changed, r + 1
 
-        labels, mb, _, _ = jax.lax.while_loop(
-            cond, body, (labels, mb, jnp.array(True), jnp.int32(0)))
-        mask_sorted = mask_sorted.at[sl].set(mb)
-
-    mask = jnp.zeros((m,), bool).at[order].set(mask_sorted[:m])
+@partial(jax.jit, static_argnames=("n", "num_buckets"))
+def filter_boruvka_msf(u: jax.Array, v: jax.Array, w: jax.Array, n: int,
+                       num_buckets: int = 8
+                       ) -> Tuple[jax.Array, jax.Array]:
+    """Jittable Filter-Borůvka. Returns (mst_mask[m], labels[n])."""
+    mask, labels, _ = filter_boruvka_msf_counted(u, v, w, n, num_buckets)
     return mask, labels
 
 
@@ -96,20 +102,8 @@ def _base_case(u, v, w, labels, n):
     """Borůvka to completion starting from the running global labels."""
     m = u.shape[0]
     max_rounds = max(1, math.ceil(math.log2(max(min(2 * m, n), 2))) + 1)
-    mst = jnp.zeros((m,), bool)
-
-    def cond(s):
-        labels_, mst_, changed, r = s
-        return changed & (r < max_rounds)
-
-    def body(s):
-        labels_, mst_, changed, r = s
-        labels_, mst_, changed = boruvka_round(u, v, w, labels_, mst_, n)
-        return labels_, mst_, changed, r + 1
-
-    labels, mst, _, _ = jax.lax.while_loop(
-        cond, body, (labels, mst, jnp.array(True), jnp.int32(0)))
-    return mst, labels
+    final = run_rounds(u, v, w, labels, jnp.zeros((m,), bool), n, max_rounds)
+    return final.mst, final.labels
 
 
 def filter_boruvka_dynamic(u: np.ndarray, v: np.ndarray, w: np.ndarray,

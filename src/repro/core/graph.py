@@ -19,6 +19,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
+
 INVALID_W = np.float32(np.inf)
 
 
@@ -70,6 +72,7 @@ class EdgeList:
         return jnp.sum(self.valid.astype(jnp.int32))
 
 
+@obs.building()
 def from_numpy(u: np.ndarray, v: np.ndarray, w: np.ndarray, n: int,
                pad_to: int | None = None) -> EdgeList:
     """Build a (optionally padded) EdgeList from host arrays.
@@ -84,13 +87,15 @@ def from_numpy(u: np.ndarray, v: np.ndarray, w: np.ndarray, n: int,
         raise CapacityError(
             f"pad_to={cap} cannot hold {m} edges ({m - cap} would be "
             "silently dropped)", dropped=m - cap)
-    uu = np.zeros(cap, np.int32)
-    vv = np.zeros(cap, np.int32)
-    ww = np.full(cap, INVALID_W, np.float32)
-    uu[:m] = u
-    vv[:m] = v
-    ww[:m] = w
-    return EdgeList(jnp.asarray(uu), jnp.asarray(vv), jnp.asarray(ww), int(n))
+    with obs.span("pack"):
+        uu = np.zeros(cap, np.int32)
+        vv = np.zeros(cap, np.int32)
+        ww = np.full(cap, INVALID_W, np.float32)
+        uu[:m] = u
+        vv[:m] = v
+        ww[:m] = w
+        return EdgeList(jnp.asarray(uu), jnp.asarray(vv), jnp.asarray(ww),
+                        int(n))
 
 
 def canonicalize_undirected(u: np.ndarray, v: np.ndarray, w: np.ndarray

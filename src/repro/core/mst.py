@@ -36,9 +36,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.boruvka import boruvka_msf
+from repro import obs
+from repro.core.boruvka import boruvka_msf_counted
 from repro.core.filter_boruvka import (boruvka_dynamic, filter_boruvka_dynamic,
-                                       filter_boruvka_msf)
+                                       filter_boruvka_msf_counted)
 from repro.core.graph import EdgeList
 
 
@@ -95,6 +96,10 @@ def minimum_spanning_forest(edges: EdgeList, *, algorithm: str = "boruvka",
     ``num_buckets`` controls filter_boruvka's weight bucketing; each
     engine keeps its own default when it is not given (static: 8,
     distributed engines: 4 levels).
+
+    The static engine, and the sharded one through
+    ``distributed_sharded_msf``, leave one record of round counters per
+    solve in ``repro.obs``.
     """
     if num_buckets is not None and num_buckets < 1:
         raise ValueError(f"num_buckets must be >= 1, got {num_buckets}")
@@ -106,15 +111,18 @@ def minimum_spanning_forest(edges: EdgeList, *, algorithm: str = "boruvka",
             kw.setdefault("num_levels", num_buckets)
         return _distributed_dispatch(edges, mesh, engine, algorithm, **kw)
     if engine == "static":
+        obs.begin()
         if algorithm == "boruvka":
-            mask, _ = boruvka_msf(edges.u, edges.v, edges.w, edges.n)
+            mask, _, counters = boruvka_msf_counted(edges.u, edges.v, edges.w,
+                                                    edges.n)
         elif algorithm == "filter_boruvka":
-            mask, _ = filter_boruvka_msf(
+            mask, _, counters = filter_boruvka_msf_counted(
                 edges.u, edges.v, edges.w, edges.n,
                 num_buckets=8 if num_buckets is None else num_buckets)
         else:
             raise ValueError(algorithm)
         weight = jnp.sum(jnp.where(mask & edges.valid, edges.w, 0.0))
+        obs.record(**counters)
         return mask, weight
     if engine == "dynamic":
         u = np.asarray(edges.u)

@@ -283,7 +283,7 @@ for fam in ("rgg2d", "grid2d"):
 
     def bucketed(uu, vv, ww, ee):
         valid = jnp.isfinite(ww)
-        lab, pre, dead0, ovf, st = _sharded_preprocess(
+        lab, pre, dead0, ovf, st, _ = _sharded_preprocess(
             uu, vv, ww, ee, valid, n, vps, vps, ("data",), "grid",
             ExchangeStats.zeros())
         return lab, pre, dead0, ovf

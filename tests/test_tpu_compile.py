@@ -10,6 +10,7 @@ persistent compilation cache stays off around them: such entries could
 not be read back without a chip.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -99,6 +100,31 @@ def test_boruvka_msf_compiles(one_chip):
     compiled = boruvka_msf.lower(iv, iv, wv, n).compile()
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 16e9
+
+
+def test_round_counter_keeps_memory_placement(one_chip, monkeypatch):
+    """The static engine's live-slot count leaves XLA's memory-space
+    assignment as it is without the count: at the Kronecker cell's size
+    every gather and scatter result keeps its memory space (a count that
+    kept its own [m] mask pushed one 2^24-slot gather's result out of
+    memory space ``S(1)``, and that gather ran a fifth slower on a v5e)."""
+    from repro.core import boruvka
+    n, m = 1 << 20, 1 << 24
+    iv = jax.ShapeDtypeStruct((m,), jnp.int32, sharding=one_chip)
+    wv = jax.ShapeDtypeStruct((m,), jnp.float32, sharding=one_chip)
+
+    def custom_fusions():
+        fn = jax.jit(lambda u, v, w: boruvka.boruvka_msf_counted.__wrapped__(
+            u, v, w, n))
+        text = fn.lower(iv, iv, wv).compile().as_text()
+        return re.findall(r"= (\S+) fusion\(.*?kind=kCustom", text)
+
+    counted = custom_fusions()
+    min_edges = boruvka._min_edges
+    monkeypatch.setattr(boruvka, "_min_edges", lambda ru, rv, w, n: (
+        *min_edges(ru, rv, w, n)[:2], jnp.int32(0)))
+    assert custom_fusions() == counted
+    assert sum("S(1)" in t for t in counted) > 4
 
 
 def test_sharded_round_step_compiles(topo):
