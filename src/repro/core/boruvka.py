@@ -32,7 +32,7 @@ from typing import NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro import obs
+from repro import compat, obs
 from repro.core.graph import EdgeList
 
 
@@ -42,10 +42,43 @@ class BoruvkaState(NamedTuple):
     changed: jax.Array   # bool  []  did the last round contract anything
     rounds: jax.Array    # int32 []  rounds executed
     live: jax.Array      # int32 []  alive edge slots summed over rounds
+    steps: jax.Array     # int32 []  pointer-doubling passes over rounds
 
 
 def _doubling_iters(n: int) -> int:
     return max(1, math.ceil(math.log2(max(n, 2))))
+
+
+def pointer_double(parent: jax.Array, n: int
+                   ) -> Tuple[jax.Array, jax.Array]:
+    """Pointer doubling (Section IV-B / Chung & Condon): ``p = p[p]``
+    until a pass changes nothing.
+
+    Returns (roots, steps): the flattened parent array and the passes
+    run (int32 []), the last of which found nothing to change.  A forest
+    of depth d >= 1 takes ceil(log2 d) + 1 passes; a forest of roots
+    alone, one.  The cap of ``_doubling_iters(n)`` passes flattens any
+    forest over n ids, and stops a parent array with a longer cycle.
+    Inside ``shard_map`` each shard stops on its own array: the loop
+    holds no collective.
+    """
+    cap = _doubling_iters(n)
+    axes = compat.vma_of(parent)
+
+    def cond(s):
+        _, i, changed = s
+        return changed & (i < cap)
+
+    def body(s):
+        p, i, _ = s
+        q = p[p]
+        return q, i + 1, jnp.any(q != p)
+
+    with obs.scope("doubling"):
+        roots, steps, _ = jax.lax.while_loop(
+            cond, body, (parent, compat.vary(jnp.int32(0), axes),
+                         compat.vary(jnp.array(True), axes)))
+    return roots, steps
 
 
 def min_edge_per_component(ru: jax.Array, rv: jax.Array, w: jax.Array,
@@ -87,11 +120,12 @@ def _min_edges(ru: jax.Array, rv: jax.Array, w: jax.Array, n: int
 def contract_components(emin: jax.Array, u: jax.Array, v: jax.Array,
                         labels: jax.Array, n: int,
                         root_mask: Optional[jax.Array] = None
-                        ) -> Tuple[jax.Array, jax.Array]:
+                        ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Pseudo-tree -> rooted-star contraction by pointer doubling.
 
-    Returns (roots[n], has[n]): the new representative of every current
-    component label, and whether the component chose an edge this round.
+    Returns (roots[n], has[n], steps): the new representative of every
+    current component label, whether the component chose an edge this
+    round, and the doubling passes run (``pointer_double``).
     ``root_mask`` forces components to stay roots (used for shared
     vertices in the distributed algorithm, Section IV-B).
     """
@@ -110,12 +144,8 @@ def contract_components(emin: jax.Array, u: jax.Array, v: jax.Array,
         # Break 2-cycles: the smaller label of the pair becomes the root.
         gp = parent[parent]
         parent = jnp.where((gp == cids) & (cids < parent), cids, parent)
-    # Pointer doubling (Section IV-B / Chung & Condon).
-    def double(_, p):
-        return p[p]
-    with obs.scope("doubling"):
-        roots = jax.lax.fori_loop(0, _doubling_iters(n), double, parent)
-    return roots, has
+    roots, steps = pointer_double(parent, n)
+    return roots, has, steps
 
 
 def boruvka_round(u: jax.Array, v: jax.Array, w: jax.Array,
@@ -130,22 +160,24 @@ def _boruvka_round_counted(u: jax.Array, v: jax.Array, w: jax.Array,
                            labels: jax.Array, mst: jax.Array, n: int,
                            root_mask: Optional[jax.Array] = None,
                            ) -> Tuple[jax.Array, jax.Array, jax.Array,
-                                      jax.Array]:
-    """``boruvka_round`` and the count of the edge slots MINEDGES could
-    choose: endpoints in different components, finite weight (int32 [])."""
+                                      jax.Array, jax.Array]:
+    """``boruvka_round``, the count of the edge slots MINEDGES could
+    choose (endpoints in different components, finite weight) and the
+    doubling passes CONTRACT ran (int32 [] each)."""
     m = u.shape[0]
     if m == 0:  # no edge to choose, and nothing to gather the choice from
-        return labels, mst, jnp.array(False), jnp.int32(0)
+        return labels, mst, jnp.array(False), jnp.int32(0), jnp.int32(0)
     with obs.scope("label_gather"):
         ru = labels[u]
         rv = labels[v]
     _, emin, live = _min_edges(ru, rv, w, n)
-    roots, has = contract_components(emin, u, v, labels, n, root_mask)
+    roots, has, steps = contract_components(emin, u, v, labels, n,
+                                            root_mask)
     with obs.scope("contract"):
         ce = jnp.clip(emin, 0, m - 1)
         mst_i = mst.astype(jnp.int32).at[ce].max(has.astype(jnp.int32))
         labels = roots[labels]
-    return labels, mst_i.astype(bool), jnp.any(has), live
+    return labels, mst_i.astype(bool), jnp.any(has), live, steps
 
 
 def run_rounds(u: jax.Array, v: jax.Array, w: jax.Array,
@@ -153,16 +185,17 @@ def run_rounds(u: jax.Array, v: jax.Array, w: jax.Array,
                ) -> BoruvkaState:
     """Borůvka rounds until none contracts or ``max_rounds`` ran."""
     init = BoruvkaState(labels=labels, mst=mst, changed=jnp.array(True),
-                        rounds=jnp.int32(0), live=jnp.int32(0))
+                        rounds=jnp.int32(0), live=jnp.int32(0),
+                        steps=jnp.int32(0))
 
     def cond(s: BoruvkaState):
         return s.changed & (s.rounds < max_rounds)
 
     def body(s: BoruvkaState):
-        labels, mst, changed, live = _boruvka_round_counted(
+        labels, mst, changed, live, steps = _boruvka_round_counted(
             u, v, w, s.labels, s.mst, n)
         return BoruvkaState(labels, mst, changed, s.rounds + 1,
-                            s.live + live)
+                            s.live + live, s.steps + steps)
 
     return jax.lax.while_loop(cond, body, init)
 
@@ -170,7 +203,8 @@ def run_rounds(u: jax.Array, v: jax.Array, w: jax.Array,
 def round_counters(state: BoruvkaState, m: int) -> dict:
     """``obs.record``'s counters of one ``run_rounds`` over m slots."""
     return {"rounds": state.rounds, "live_slots": state.live,
-            "slot_rounds": state.rounds * m}
+            "slot_rounds": state.rounds * m,
+            "doubling_steps": state.steps}
 
 
 @partial(jax.jit, static_argnames=("n", "max_rounds"))

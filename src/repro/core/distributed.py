@@ -41,6 +41,7 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from repro import compat, obs
+from repro.core.boruvka import _doubling_iters, pointer_double
 from repro.core.graph import INVALID_W, CapacityError
 
 # "no chosen edge" sentinel in eid space, shared by every engine (and
@@ -151,10 +152,6 @@ def build_dist_graph(u: np.ndarray, v: np.ndarray, w: np.ndarray, n: int,
 # --------------------------------------------------------------------------
 # shard-local building blocks (all run inside shard_map)
 # --------------------------------------------------------------------------
-
-def _doubling_iters(n: int) -> int:
-    return max(1, math.ceil(math.log2(max(n, 2))))
-
 
 def shrink_schedule(full: int, floor: int = 1) -> Tuple[int, ...]:
     """Geometric halving ladder ``(full, ceil(full/2), ..., floor)``.
@@ -291,8 +288,7 @@ def _local_preprocessing_core(u, v, w, eid, valid, n: int,
         parent = jnp.where(eligible, other, iota)
         gp = parent[parent]
         parent = jnp.where((gp == iota) & (iota < parent), iota, parent)
-        roots = lax.fori_loop(0, _doubling_iters(n), lambda _, p_: p_[p_],
-                              parent)
+        roots, _ = pointer_double(parent, n)
         mst = mst.at[ce].max(eligible.astype(jnp.int32))
         labels = roots[labels]
         return labels, mst, jnp.any(eligible), r + 1
@@ -368,8 +364,7 @@ def _distributed_rounds(u, v, w, eid, valid, labels, mst, n: int,
         parent = jnp.where(has & (other >= 0), other, iota)
         gp = parent[parent]
         parent = jnp.where((gp == iota) & (iota < parent), iota, parent)
-        roots = lax.fori_loop(0, _doubling_iters(n), lambda _, p_: p_[p_],
-                              parent)
+        roots, _ = pointer_double(parent, n)
         # mark the canonical directed copy exactly once
         mst = mst | (win & (u < v))
         labels = roots[labels]
@@ -469,8 +464,7 @@ def _distributed_rounds_shrink(u, v, w, eid, valid, labels, mst, n: int,
         parent = jnp.where(has & (other >= 0), other, sid)
         gp = parent[parent]
         parent = jnp.where((gp == sid) & (sid < parent), sid, parent)
-        roots = lax.fori_loop(0, _doubling_iters(s),
-                              lambda _, p_: p_[p_], parent)
+        roots, _ = pointer_double(parent, s)
         mst = mst | ((win_u | win_v) & (u < v))
         # labels: active vertices point at the root slot's representative
         lab_slot = cid[labels]                     # [n]

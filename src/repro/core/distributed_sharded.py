@@ -168,9 +168,9 @@ from repro.comm import faults
 from repro.comm.exchange import (ExchangeStats, _hops, reply,
                                  routed_exchange, scatter_updates,
                                  scatter_updates_grid)
+from repro.core.boruvka import _doubling_iters, pointer_double
 from repro.core.distributed import (ESENT, CommStats, DistGraph,
-                                    _doubling_iters, _weight_pivots,
-                                    quantize_capacity)
+                                    _weight_pivots, quantize_capacity)
 from repro.core.msf_checkpoint import CheckpointError, MSFCheckpoint
 from repro.core.plan import GhostPlan, RoundPlan, RoundSpec
 from repro.kernels.segmin.ops import run_metadata
@@ -622,12 +622,13 @@ def _sharded_preprocess(u, v, w, eid, valid, n: int, vps: int,
     Kruskal oracle.
 
     Returns (lab [vps], pre_mst [cap] bool, dead0 [cap] bool, overflow,
-    stats, (rounds, live)): this shard's round count and its alive slots
-    summed over the rounds (int32 []).  The owner scatter ships one (vid,
-    root) pair per *changed distinct vertex* (L = cap, down from the old
-    L = n): an owner owns ``vps`` vertices and a shard has at most cap
-    distinct sources, so the effective ``min(capacity, cap)`` stays
-    overflow-free by construction for the default ``label_capacity``.
+    stats, (rounds, live, steps)): this shard's round count, and its
+    alive slots and pointer-doubling passes summed over the rounds
+    (int32 []).  The owner scatter ships one (vid, root) pair per
+    *changed distinct vertex* (L = cap, down from the old L = n): an
+    owner owns ``vps`` vertices and a shard has at most cap distinct
+    sources, so the effective ``min(capacity, cap)`` stays overflow-free
+    by construction for the default ``label_capacity``.
     """
     names = tuple(axes)
     cap = u.shape[0]
@@ -673,7 +674,7 @@ def _sharded_preprocess(u, v, w, eid, valid, n: int, vps: int,
     max_rounds = _doubling_iters(nloc) + 1
 
     def round_(state):
-        lab, mst, _, r, live = state
+        lab, mst, _, r, live, steps = state
         with obs.scope("label_gather"):
             ru = lab[du]
             rvx = jnp.where(v_found, lab[dv], sent)
@@ -709,24 +710,22 @@ def _sharded_preprocess(u, v, w, eid, valid, n: int, vps: int,
             parent = jnp.where(eligible, other, iota)
             gp = parent[parent]
             parent = jnp.where((gp == iota) & (iota < parent), iota, parent)
-        with obs.scope("doubling"):
-            roots = lax.fori_loop(0, _doubling_iters(nloc),
-                                  lambda _, p_: p_[p_], parent)
+        roots, k = pointer_double(parent, nloc)
         with obs.scope("contract"):
             mst = mst.at[ce].max(eligible.astype(jnp.int32))
             lab = roots[lab]
-        return lab, mst, jnp.any(eligible), r + 1, live
+        return lab, mst, jnp.any(eligible), r + 1, live, steps + k
 
     def cond(state):
         return state[2] & (state[3] < max_rounds)
 
     lab0 = compat.vary(iota, names)
     mst0 = compat.vary(jnp.zeros((cap,), jnp.int32), names)
-    live0 = compat.vary(jnp.int32(0), names)
-    lab, mst, _, rounds, live = lax.while_loop(
+    zero = compat.vary(jnp.int32(0), names)
+    lab, mst, _, rounds, live, steps = lax.while_loop(
         cond, round_,
         (lab0, mst0, compat.vary(jnp.array(True), names), jnp.int32(0),
-         live0))
+         zero, zero))
 
     # --- one routed (vid, root) scatter to the owners ------------------
     with obs.scope("exchange"):
@@ -748,7 +747,7 @@ def _sharded_preprocess(u, v, w, eid, valid, n: int, vps: int,
         same = v_found & (lab[du] == lab[dv])
         dead0 = (u == v) | same  # locally-internal edges incl. self-loops
     return (lab_out, mst.astype(bool), dead0, ex.overflow, ex.stats,
-            (rounds, live))
+            (rounds, live, steps))
 
 
 def _owner_scatter_min(comp, wc, ec, oc, okc, base, vps: int,
@@ -1317,15 +1316,17 @@ def _sharded_prep_shard_fn(u, v, w, eid, n: int, vps: int,
                            schedule: str):
     """The preprocessing program.  Returns (lab, pre_mst, dead0, overflow,
     counters, *stat leaves): ``counters`` are ``obs.record``'s, summed
-    over the shards (rounds: the most any shard ran)."""
+    over the shards (rounds and doubling passes: the most any shard
+    ran)."""
     names = tuple(axes)
     valid = jnp.isfinite(w)
-    lab, pre_mst, dead0, ovf, st, (rounds, live) = _sharded_preprocess(
-        u, v, w, eid, valid, n, vps, cap_label, names, schedule,
-        ExchangeStats.zeros())
+    lab, pre_mst, dead0, ovf, st, (rounds, live, steps) = \
+        _sharded_preprocess(u, v, w, eid, valid, n, vps, cap_label, names,
+                            schedule, ExchangeStats.zeros())
     counters = {"rounds": lax.pmax(rounds, names),
                 "live_slots": lax.psum(live, names),
-                "slot_rounds": lax.psum(rounds * u.shape[0], names)}
+                "slot_rounds": lax.psum(rounds * u.shape[0], names),
+                "doubling_steps": lax.pmax(steps, names)}
     return (lab, pre_mst, dead0, ovf, counters) + _stat_leaves(st)
 
 
@@ -2109,7 +2110,8 @@ def _shrinking_capacity_msf(graph: DistGraph, n: int,
     obs.record(rounds=[prep_counters.get("rounds", 0), len(step_live)],
                live_slots=[prep_counters.get("live_slots", 0), step_live],
                slot_rounds=[prep_counters.get("slot_rounds", 0),
-                            len(step_live) * p * cap])
+                            len(step_live) * p * cap],
+               doubling_steps=prep_counters.get("doubling_steps", 0))
     return out
 
 

@@ -26,9 +26,10 @@ on one clock with the device's events:
 The ring and the span totals are per process.  ``minimum_spanning_forest``
 (static engine), ``distributed_sharded_msf`` (host-driven shrinking
 path) and the measurement pass of ``plan_sharded_msf`` record
-``rounds``, ``live_slots`` and ``slot_rounds``: the Borůvka
-rounds executed, and the alive edge slots and all edge slots summed over
-those rounds.
+``rounds``, ``live_slots``, ``slot_rounds`` and ``doubling_steps``: the
+Borůvka rounds executed, the alive edge slots and all edge slots summed
+over those rounds, and the pointer-doubling passes CONTRACT ran (the
+sharded driver: its preprocessing program's).
 """
 from __future__ import annotations
 

@@ -249,6 +249,59 @@ def test_sharded_grid_push_p32_oracle():
     assert "OK" in out
 
 
+# every engine whose CONTRACT runs dense pointer doubling (stopping at the
+# first pass that changes nothing): static Borůvka and Filter-Borůvka, the
+# replicated engine's three rounds (preprocessing on, so its contraction
+# core doubles too) and the sharded engine's preprocessing, whose shards
+# stop their doubling loops at different passes
+DENSE_DOUBLING = """
+from jax.sharding import Mesh
+from repro import obs
+from repro.core import oracle
+from repro.core.boruvka import boruvka_msf
+from repro.core.distributed import build_dist_graph, distributed_msf
+from repro.core.distributed_sharded import distributed_sharded_msf
+from repro.core.filter_boruvka import filter_boruvka_msf
+from repro.data import generators
+
+mesh = Mesh(np.array(jax.devices()), ("data",))
+if FAMILY == "rgg2d":
+    u, v, w, n = generators.rgg2d(4096, 8.0, 11)
+else:
+    u, v, w, n = generators.rmat(12, 16 * 4096, 11)
+want = oracle.kruskal_fast(u, v, w, n)
+ju, jv, jw = jnp.asarray(u), jnp.asarray(v), jnp.asarray(w)
+got = {"boruvka_msf": boruvka_msf(ju, jv, jw, n)[0],
+       "filter_boruvka_msf": filter_boruvka_msf(ju, jv, jw, n)[0]}
+g, cap = build_dist_graph(u, v, w, n, 8)
+for algo in ("boruvka", "filter_boruvka", "boruvka_shrink"):
+    res = distributed_msf(g, n, mesh, algorithm=algo)
+    got["distributed/" + algo] = res[0]
+obs.clear()
+res = distributed_sharded_msf(g, n, mesh)
+assert int(res[4]) == 0, int(res[4])
+got["distributed_sharded"] = res[0]
+(rec,) = obs.solve_records()
+# the preprocessing program's passes, at least one a round
+assert rec["doubling_steps"] > 0, rec
+for name, mask in got.items():
+    mask = np.asarray(mask)
+    if name.startswith("distributed"):
+        sel = np.unique(np.asarray(g.eid)[mask])
+        mask = np.zeros(len(u), bool)
+        mask[sel] = True
+    assert np.array_equal(mask, want), (FAMILY, name)
+print("OK")
+"""
+
+
+@pytest.mark.parametrize("family", ["rgg2d", "kronecker"])
+def test_dense_doubling_engines_exact(family):
+    out = run_multidevice(f"FAMILY = {family!r}\n" + DENSE_DOUBLING,
+                          ndev=8, timeout=1800)
+    assert "OK" in out
+
+
 @settings(max_examples=40, deadline=None)
 @given(st.data())
 def test_property_random_graphs_match_oracle(data):

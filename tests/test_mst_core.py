@@ -1,11 +1,13 @@
 """Core MSF correctness: jittable Borůvka + Filter-Borůvka vs Kruskal oracle."""
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from tests.helpers.hypothesis_compat import given, settings, st
 
 from repro.core import oracle
-from repro.core.boruvka import boruvka_msf
+from repro.core.boruvka import (_doubling_iters, boruvka_msf,
+                                boruvka_msf_counted, pointer_double)
 from repro.core.filter_boruvka import (boruvka_dynamic,
                                        filter_boruvka_dynamic,
                                        filter_boruvka_msf)
@@ -169,3 +171,88 @@ def test_kruskal_fast_matches_kruskal(family):
                 w[rng.random(m) < 0.1] = np.inf
         want, _ = oracle.kruskal(u, v, w, n)
         assert np.array_equal(oracle.kruskal_fast(u, v, w, n), want), seed
+
+
+# --------------------------------------------------------------------------
+# CONTRACT's pointer doubling: stops at the first pass that changes nothing
+# --------------------------------------------------------------------------
+
+def _parent_array(kind, n, seed):
+    rng = np.random.default_rng(seed)
+    if kind == "forest":  # every node points at an earlier one, or itself
+        perm = rng.permutation(n)
+        up = (rng.random(n) * np.arange(n)).astype(np.int64)
+        par = np.where(rng.random(n) < 0.1, np.arange(n), up)
+        out = np.empty(n, np.int32)
+        out[perm] = perm[par]
+        return out
+    if kind == "pseudoforest":  # one cycle per component, of any length
+        return rng.integers(0, n, n).astype(np.int32)
+    return np.minimum(np.arange(n) + 1, n - 1).astype(np.int32)  # path
+
+
+def _depth(par):
+    depth = np.zeros(len(par), np.int64)
+    p = par.copy()
+    while (p != par[p]).any():
+        depth += p != par[p]
+        p = par[p]
+    return int((depth + (par != np.arange(len(par)))).max())
+
+
+@pytest.mark.parametrize("kind,n,seed", [
+    ("forest", 1000, 0), ("forest", 4096, 1), ("forest", 3, 2),
+    ("pseudoforest", 1000, 3), ("pseudoforest", 4096, 4), ("path", 513, 0)])
+def test_pointer_double_matches_fixed_schedule(kind, n, seed):
+    """The same roots as ``_doubling_iters(n)`` passes of ``p = p[p]``; on
+    a forest of depth d, ceil(log2 d) + 1 passes (the cap at most)."""
+    par = _parent_array(kind, n, seed)
+    want = par.copy()
+    for _ in range(_doubling_iters(n)):
+        want = want[want]
+    roots, steps = pointer_double(jnp.asarray(par), n)
+    assert np.array_equal(np.asarray(roots), want)
+    if kind != "pseudoforest":
+        d = _depth(par)
+        need = int(np.ceil(np.log2(d))) + 1 if d else 1
+        assert int(steps) == min(need, _doubling_iters(n))
+    assert 1 <= int(steps) <= _doubling_iters(n)
+
+
+@pytest.mark.parametrize("k", [2, 5, 10])
+@pytest.mark.parametrize("algo", ["boruvka", "filter_boruvka"])
+def test_deep_path_converges_within_cap(k, algo):
+    """A path of 2^k vertices whose weights fall along it: every vertex
+    chooses its next edge, so round 1 builds one tree of depth 2^k - 2,
+    which needs all ``_doubling_iters(2^k) = k`` passes."""
+    n = 1 << k
+    u = np.arange(n - 1, dtype=np.int32)
+    v = u + 1
+    w = (n - u).astype(np.float32)
+    edges = from_numpy(u, v, w, n)
+    mask, _ = minimum_spanning_forest(edges, algorithm=algo,
+                                      num_buckets=1)
+    want, _ = oracle.kruskal(u, v, w, n)
+    assert np.array_equal(np.asarray(mask), want)
+    _, labels, counters = boruvka_msf_counted(edges.u, edges.v, edges.w, n)
+    assert len(set(np.asarray(labels).tolist())) == 1
+    # round 1 runs to the cap; round 2 finds every edge internal
+    assert (int(counters["rounds"]), int(counters["doubling_steps"])) == \
+        (2, k + 1)
+
+
+def test_doubling_steps_hand_count():
+    """Two paths 0-1-2-3 and 4-5-6-7, weights falling along each, and a
+    heavy bridge 3-4.  Round 1: trees 0->1->2<->3 and 4->5->6<->7 (the
+    2-cycles keep 2 and 6 as roots), depth 2: one pass flattens, one
+    finds nothing.  Round 2: both components choose the bridge, 2 <-> 6
+    with 2 as root, depth 1: one pass.  Round 3 finds no edge: one pass.
+    """
+    u = np.array([0, 1, 2, 4, 5, 6, 3], np.int32)
+    v = np.array([1, 2, 3, 5, 6, 7, 4], np.int32)
+    w = np.array([3, 2, 1, 13, 12, 11, 20], np.float32)
+    mask, labels, counters = boruvka_msf_counted(
+        jnp.asarray(u), jnp.asarray(v), jnp.asarray(w), 8)
+    assert np.asarray(mask).all() and set(np.asarray(labels)) == {2}
+    assert int(counters["rounds"]) == 3
+    assert int(counters["doubling_steps"]) == 2 + 1 + 1

@@ -37,24 +37,39 @@ def _graph(seed=0, n=N, m=300):
 
 
 def _recount(u, v, w, comp, max_rounds):
-    """Borůvka rounds over the slots (u, v, w) from the partition
-    ``comp`` (updated in place): the alive slots of every round run,
-    the last one being the round that found nothing to contract."""
-    live = []
+    """Borůvka rounds over the slots (u, v, w) from the component labels
+    ``comp`` (updated in place): the alive slots of every round run, the
+    last one being the round that found nothing to contract, and the
+    pointer-doubling passes of each round's CONTRACT.  A component points
+    at the other end of its (w, slot)-least edge; of a 2-cycle the
+    smaller label is the root; doubling stops after the first pass that
+    changes nothing, or after ceil(log2 n) passes."""
+    n = len(comp)
+    ids = np.arange(n)
+    live, steps = [], []
     while len(live) < max_rounds:
         alive = comp[u] != comp[v]
         live.append(int(alive.sum()))
-        if not alive.any():
-            break
         best = {}
         for i in np.nonzero(alive)[0]:
             for c in (comp[u[i]], comp[v[i]]):
                 if c not in best or (w[i], i) < (w[best[c]], best[c]):
                     best[c] = i
-        for i in set(best.values()):
-            a, b = comp[u[i]], comp[v[i]]
-            comp[comp == max(a, b)] = min(a, b)
-    return live
+        par = ids.copy()
+        for c, i in best.items():
+            par[c] = comp[u[i]] + comp[v[i]] - c
+        par = np.where((par[par] == ids) & (ids < par), ids, par)
+        k = 0
+        while k < max(1, int(np.ceil(np.log2(max(n, 2))))):
+            k += 1
+            if (par[par] == par).all():
+                break
+            par = par[par]
+        steps.append(k)
+        comp[:] = par[comp]
+        if not alive.any():
+            break
+    return live, steps
 
 
 def _max_rounds(n, m):
@@ -68,10 +83,11 @@ def test_static_counters_match_recount():
     minimum_spanning_forest(e)
     (rec,) = obs.solve_records()
     uu, vv, ww = (np.asarray(x) for x in (e.u, e.v, e.w))
-    live = _recount(uu, vv, ww, np.arange(N), _max_rounds(N, 512))
+    live, steps = _recount(uu, vv, ww, np.arange(N), _max_rounds(N, 512))
     assert rec["rounds"] == len(live) > 2
     assert rec["live_slots"] == sum(live)
     assert rec["slot_rounds"] == 512 * len(live)
+    assert rec["doubling_steps"] == sum(steps) > len(live)
     assert rec["host_s"]["pack"] > 0
 
 
@@ -84,14 +100,16 @@ def test_filter_counters_match_recount():
     uu, vv, ww = (np.asarray(x) for x in (e.u, e.v, e.w))
     order = np.argsort(ww, kind="stable")
     comp = np.arange(N)
-    rounds = live = 0
+    rounds = live = steps = 0
     for b in range(4):
         sl = order[b * 128:(b + 1) * 128]
-        got = _recount(uu[sl], vv[sl], ww[sl], comp, _bucket_rounds(128, N))
+        got, k = _recount(uu[sl], vv[sl], ww[sl], comp,
+                          _bucket_rounds(128, N))
         rounds += len(got)
         live += sum(got)
-    assert (rec["rounds"], rec["live_slots"], rec["slot_rounds"]) == \
-        (rounds, live, 128 * rounds)
+        steps += sum(k)
+    assert (rec["rounds"], rec["live_slots"], rec["slot_rounds"],
+            rec["doubling_steps"]) == (rounds, live, 128 * rounds, steps)
 
 
 @pytest.mark.parametrize("prep", [True, False])
@@ -107,15 +125,18 @@ def test_sharded_counters_match_recount(prep):
     gu, gv, gw, ge = (np.asarray(x) for x in g)
     # the (w, eid) order, both directed copies of an edge alike
     key = gw.astype(np.float64) * 1e6 + np.where(np.isfinite(gw), ge, 0)
-    live = _recount(gu, gv, key, np.arange(N), 64)
+    live, steps = _recount(gu, gv, key, np.arange(N), 64)
     if prep:
         # at p=1 preprocessing contracts every edge, ending on an empty
         # round; no round step runs after it
         assert trace == []
         assert rec["rounds"] == len(live)
+        assert rec["doubling_steps"] == sum(steps)
     else:
-        # the driver skips the trailing round whose host bound is zero
+        # the driver skips the trailing round whose host bound is zero;
+        # the routed rounds' doubling is not counted
         assert rec["rounds"] == len(live) - 1 == len(trace)
+        assert rec["doubling_steps"] == 0
     assert rec["live_slots"] == sum(live)
     assert rec["slot_rounds"] == cap * rec["rounds"]
     spans = set(rec["host_s"])
